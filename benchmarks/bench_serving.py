@@ -53,17 +53,21 @@ def _warm_jit(spec, n_slots: int, max_prompt: int = 8) -> None:
     jit cache; prefill recompiles per batch size, decode is fixed-shape)."""
     import jax.numpy as jnp
 
+    from repro.kernels.ops import pallas_interpret
     from repro.serving import kv
 
     params = kv.init_params(spec, seed=0)
+    interpret = pallas_interpret()
     L, Hkv, hd = spec.n_layers, spec.n_kv_heads, spec.head_dim
     for b in range(1, max(2, n_slots) + 1):
         kv.prefill(params, jnp.zeros((b, max_prompt), jnp.int32),
-                   jnp.ones((b,), jnp.int32), spec=spec)
+                   jnp.ones((b,), jnp.int32), spec=spec,
+                   interpret=interpret)
     zeros = jnp.zeros((L, n_slots, spec.max_len, Hkv, hd), jnp.float32)
     kv.decode_step(params, zeros, zeros,
                    jnp.ones((n_slots,), jnp.int32),
-                   jnp.zeros((n_slots,), jnp.int32), spec=spec)
+                   jnp.zeros((n_slots,), jnp.int32), spec=spec,
+                   interpret=interpret)
 
 
 def run_serving(*, profile: str = "bursty", n_per_burst: int = 4,

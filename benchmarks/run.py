@@ -1,6 +1,7 @@
 """Benchmark harness entry point — one suite per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV per the harness contract.
+Prints ``name,us_per_call,derived`` CSV per the harness contract, and
+exits non-zero when any suite raised (its row reads ``<suite>_FAILED``).
 
   PYTHONPATH=src python -m benchmarks.run [suite ...]
 
@@ -32,9 +33,12 @@ def _train_suite():
              f"over 12 steps (full run: examples/train_lm.py)")], {}
 
 
-def main() -> None:
+def main() -> int:
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     want = sys.argv[1:] or list(SUITES)
     rows = []
+    failed = []
     for suite in want:
         try:
             if suite == "adaptation":
@@ -69,16 +73,21 @@ def main() -> None:
                 r, _ = m.run()
             else:
                 print(f"# unknown suite {suite!r}", file=sys.stderr)
+                failed.append(suite)
                 continue
             rows.extend(r)
         except Exception:
             print(f"# suite {suite} FAILED:", file=sys.stderr)
             traceback.print_exc()
             rows.append((f"{suite}_FAILED", 0.0, "see stderr"))
+            failed.append(suite)
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
+    if failed:
+        print(f"# failed suites: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -172,7 +172,7 @@ def refine_flow(centroids: np.ndarray) -> Flow:
     """
     from repro.kernels import ops
     C = jnp.asarray(centroids, jnp.float32)
-    interpret = jax.default_backend() != "tpu"
+    interpret = ops.pallas_interpret()
 
     # sequential: the census below zips assignments against injection
     # order, so carriers must complete in FIFO (data-parallel instances

@@ -18,13 +18,9 @@ suppressions otherwise.
 from __future__ import annotations
 
 import os
+import tomllib
 from dataclasses import dataclass
 from typing import Iterable, List, Optional, Tuple
-
-try:                       # 3.11+
-    import tomllib
-except ImportError:        # 3.10: the container ships tomli
-    import tomli as tomllib  # type: ignore[no-redef]
 
 from .findings import Finding
 

@@ -30,7 +30,7 @@ class ShmRing:
             self.shm = shared_memory.SharedMemory(create=True, size=size)
             self.owner = True
         else:
-            # worker-side attach.  NOTE: on Python 3.10 attaching also
+            # worker-side attach.  NOTE: on Python 3.12 attaching also
             # registers the segment with the resource tracker — which mp
             # spawn children INHERIT from the parent, so the registry is a
             # shared set and the double-register is harmless; the parent's

@@ -137,8 +137,22 @@ def _result_rows_perrow(pellet, payloads: List[Any]):
     return wire, None
 
 
+def _pin_jax_to_cpu() -> None:
+    """Keep this worker off the accelerator.
+
+    The chip belongs to the parent process: a second process that opens
+    it fails or hangs.  Called before the worker's first JAX call, so
+    offloaded pellets that compute with JAX run on the host CPU (and any
+    process the worker starts inherits the setting).
+    """
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def worker_main(conn, tx_name: str, rx_name: str, ring_bytes: int,
                 host_name: str) -> None:
+    _pin_jax_to_cpu()
     from .shm import ShmRing
     tx = ShmRing.attach(tx_name, ring_bytes)   # parent → worker
     rx = ShmRing.attach(rx_name, ring_bytes)   # worker → parent

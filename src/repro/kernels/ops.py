@@ -22,6 +22,24 @@ LANE = 128
 SUBLANE = 8
 
 
+def pallas_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode on the default backend.
+
+    ``False`` on a TPU (compiled Mosaic kernels), ``True`` on the CPU (the
+    interpreter the tests use).  Any other platform raises: a kernel never
+    drops silently to the interpreter.  Ask when a pellet or flow is
+    built, never at import — the question initialises JAX's backend.
+    """
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default JAX backend is {platform!r}")
+
+
 def _pad_last(x: jnp.ndarray, mult: int = LANE) -> Tuple[jnp.ndarray, int]:
     d = x.shape[-1]
     pad = (-d) % mult

@@ -20,7 +20,7 @@ from .mlp import ffn, mlp_layout, moe_layout
 from .ssm import (mamba1_decode, mamba1_forward, mamba1_layout, mamba2_decode,
                   mamba2_forward, mamba2_layout)
 
-NO_WINDOW = jnp.int32(2 ** 30)  # "global attention" sentinel for traced windows
+NO_WINDOW = 2 ** 30  # "global attention" sentinel for traced windows
 
 
 def norm_spec(cfg: ModelConfig) -> PSpec:

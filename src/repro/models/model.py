@@ -94,7 +94,7 @@ class Model:
         if cfg.sliding_window is None:
             return None
         return jnp.asarray(
-            [layer_window(cfg, i) or int(NO_WINDOW)
+            [layer_window(cfg, i) or NO_WINDOW
              for i in range(cfg.n_layers)], dtype=jnp.int32)
 
     def _embed(self, params, tokens, ctx: ShardCtx) -> jnp.ndarray:

@@ -35,15 +35,12 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional
 
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax.numpy as jnp
-except Exception:                                     # pragma: no cover
-    jnp = None
 
 from ..api.builder import Flow
 from ..core.pellet import Drop, KeyedEmit, PushPellet
+from ..kernels.ops import pallas_interpret
 from . import kv
 from .kv import LMSpec, init_params
 from .scheduler import Scheduler, make_request
@@ -72,13 +69,12 @@ class PrefillPellet(PushPellet):
     out_ports = ("out",)
 
     def __init__(self, params: Dict[str, Any], spec: LMSpec, *,
-                 version: int = 0, ref_path: bool = False,
-                 interpret: Optional[bool] = None):
+                 version: int = 0, ref_path: bool = False):
         self.params = params
         self.spec = spec
         self.model_version = int(version)
         self.ref_path = bool(ref_path)
-        self.interpret = kv.INTERPRET if interpret is None else bool(interpret)
+        self.interpret = pallas_interpret()
 
     def compute_array(self, cols: Any) -> Any:
         if not isinstance(cols, dict) or "tokens" not in cols:
@@ -136,14 +132,13 @@ class DecodePellet(PushPellet):
                       "tick_pending", "n_steps", "n_spliced")
 
     def __init__(self, params: Dict[str, Any], spec: LMSpec, *,
-                 n_slots: int = 4, version: int = 0, ref_path: bool = False,
-                 interpret: Optional[bool] = None):
+                 n_slots: int = 4, version: int = 0, ref_path: bool = False):
         self.params = params
         self.spec = spec
         self.n_slots = int(n_slots)
         self.model_version = int(version)
         self.ref_path = bool(ref_path)
-        self.interpret = kv.INTERPRET if interpret is None else bool(interpret)
+        self.interpret = pallas_interpret()
         L, S = spec.n_layers, spec.max_len
         shape = (L, self.n_slots, S, spec.n_kv_heads, spec.head_dim)
         self.k = jnp.zeros(shape, dtype=jnp.float32)

@@ -34,9 +34,6 @@ import numpy as np
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 
-#: Pallas kernels need interpret mode off-TPU; resolved once at import.
-INTERPRET = jax.default_backend() != "tpu"
-
 
 @dataclasses.dataclass(frozen=True)
 class LMSpec:
@@ -123,9 +120,11 @@ def _prefill_impl(params: Dict[str, jnp.ndarray], tokens: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "interpret"))
-def prefill(params, tokens, lengths, *, spec: LMSpec,
-            interpret: bool = INTERPRET):
-    """Kernel path: causal attention via the flash_attention Pallas kernel."""
+def prefill(params, tokens, lengths, *, spec: LMSpec, interpret: bool):
+    """Kernel path: causal attention via the flash_attention Pallas kernel.
+
+    ``interpret`` comes from ``kernels.ops.pallas_interpret()``, asked when
+    the caller is built."""
     return _prefill_impl(
         params, tokens, lengths, spec,
         lambda q, k, v: kops.flash_attention_op(
@@ -167,7 +166,7 @@ def _decode_impl(params: Dict[str, jnp.ndarray], k_cache: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("spec", "interpret"))
 def decode_step(params, k_cache, v_cache, lengths, tokens, *, spec: LMSpec,
-                interpret: bool = INTERPRET):
+                interpret: bool):
     """One continuous-batching decode step over every slot, driven by the
     decode_attention (flash-decode) Pallas kernel.
 

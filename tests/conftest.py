@@ -1,27 +1,39 @@
-"""Shared test configuration: per-test ceilings + JAX compile cache.
+"""Shared test configuration: CPU platform, per-test ceilings, JAX compile
+cache.
+
+* The suite runs on the CPU (``JAX_PLATFORMS=cpu`` unless set), with
+  Pallas kernels interpreted: it never takes an accelerator.  The chip is
+  reached only through ``chip_smoke.py``.
 
 * Every test runs under a wall-clock ceiling (default 120 s) enforced with a
   SIGALRM watchdog, so a hung dataflow fails fast instead of wedging CI.
   Override per test with ``@pytest.mark.timeout(seconds)`` — the marker is
   compatible with pytest-timeout, which takes over transparently when
   installed (we then skip the built-in watchdog).
-* The JAX persistent compilation cache is enabled (repo-local
-  ``.jax_cache/``): the model/kernel smoke tests are dominated by XLA
-  compilation, so warm reruns and cached CI runs cut minutes of wall time.
+* The JAX persistent compilation cache is enabled
+  (``repro.launch.use_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` or the
+  checkout's ``.jax_cache/``): the model/kernel smoke tests are dominated
+  by XLA compilation, so warm reruns and cached CI runs cut minutes.
 """
+import importlib.util
 import math
 import os
 import pathlib
 import signal
+import sys
 import threading
 
 import pytest
 
-# -- JAX persistent compilation cache (must be set before jax imports) -------
-_CACHE = pathlib.Path(__file__).resolve().parent.parent / ".jax_cache"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(_CACHE))
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+
+# -- CPU platform + JAX persistent compilation cache (before jax imports) ----
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
+from repro.launch import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 
 DEFAULT_TIMEOUT_S = 120.0
 
@@ -85,3 +97,14 @@ def wait_until(predicate, *, timeout: float = 10.0,
             return True
         time.sleep(interval)
     return bool(predicate())
+
+
+def load_chip_smoke():
+    """The repo-root ``chip_smoke.py`` as a module (loaded once)."""
+    if "chip_smoke" not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", ROOT / "chip_smoke.py")
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules["chip_smoke"] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules["chip_smoke"]
