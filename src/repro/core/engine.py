@@ -48,7 +48,7 @@ from .message import Message, _next_seq
 from .patterns import SPLITS, Split, make_split
 from .pellet import (BatchItemError, Drop, FnPellet, KeyedEmit, Pellet,
                      PullPellet, PushPellet, TuplePellet, WindowPellet)
-from ..telemetry import TRACE_KEY, Telemetry, trace_of
+from ..telemetry import NO_SPAN, TRACE_KEY, Telemetry, span, trace_of
 
 ALPHA = 4  # pellet instances per core (§III)
 
@@ -429,6 +429,7 @@ class Flake:
             self._tele_array = None
             self._tele_degrade = None
             _stall = None
+        self._bind(self._proto)
         self._channel_capacity = channel_capacity
         self._wake = threading.Condition()
         self.inputs: Dict[str, Channel] = {
@@ -616,6 +617,7 @@ class Flake:
             raise ValueError("mode must be 'sync' or 'async'")
         if new_proto is None:
             new_proto = factory()
+        self._bind(new_proto)
         if tuple(new_proto.in_ports) != tuple(self._proto.in_ports) or \
            tuple(new_proto.out_ports) != tuple(self._proto.out_ports):
             raise ValueError(
@@ -741,6 +743,22 @@ class Flake:
         with self._wake:
             self._wake.notify_all()
 
+    # -- telemetry ----------------------------------------------------------------
+    def _bind(self, proto: Pellet) -> Pellet:
+        """Hand a freshly built pellet this stage's telemetry: pellets that
+        count their own work define ``bind_telemetry(telemetry, stage)``."""
+        bind = getattr(proto, "bind_telemetry", None)
+        if bind is not None and self._tele is not None:
+            bind(self._tele, self.name)
+        return proto
+
+    def _span(self, name: str, **meta: Any):
+        """A ``floe.*`` profiler span on this stage; the shared no-op when
+        telemetry is off (no span object is built)."""
+        if self._tele is None:
+            return NO_SPAN
+        return span(name, stage=self.name, **meta)
+
     # -- dispatch ---------------------------------------------------------------
     def _dispatch_loop(self) -> None:
         proto = self._proto
@@ -766,7 +784,7 @@ class Flake:
                             and not self._ready()):
                         self._wake.wait(timeout=0.05)
                 continue
-            kind, item, credits = work
+            kind, item, credits, wait = work
             with self._pellet_lock:
                 proto = self._proto
             if kind == "landmark":
@@ -776,18 +794,22 @@ class Flake:
                 self._wait_quiescent()
                 self._finish(item, credits, forward=True)
             elif proto.sequential or isinstance(proto, PullPellet):
-                self._run_inline(kind, item, credits)
+                self._run_inline(kind, item, credits, wait)
             else:
-                self._submit(kind, item, credits)
+                self._submit(kind, item, credits, wait)
 
-    def _observe_wait(self, head_ts: float, rows: int) -> None:
+    def _observe_wait(self, head_ts: float, rows: int) -> float:
         """Queue-wait histogram: time from enqueue to dispatch, observed
         once per dispatch with the batch-head's wait weighted by row count
         (``derive()`` stamps a fresh ``ts`` per hop, so ``msg.ts`` is the
-        enqueue time at this stage to within routing latency)."""
+        enqueue time at this stage to within routing latency).  Returns
+        the wait, which the dispatch's sampled trace spans report."""
         w = self._tele_wait
-        if w is not None and rows > 0:
-            w.observe(max(time.time() - head_ts, 0.0), n=rows)
+        if w is None or rows <= 0:
+            return 0.0
+        wait = max(time.time() - head_ts, 0.0)
+        w.observe(wait, n=rows)
+        return wait
 
     def _ready(self) -> bool:
         """Is a unit of work available right now?"""
@@ -797,10 +819,12 @@ class Flake:
         return any(len(c) > 0 for c in self.inputs.values())
 
     def _collect(self):
-        """Pop one unit of work: ('msg', Message, credits) |
-        ('batch', [Message], credits) | ('tuple', {port: Message}, credits) |
-        ('window', [Message], credits) | ('pull', [Message], credits) |
-        ('landmark', Message, 1) | None.
+        """Pop one unit of work: ('msg', Message, credits, wait) |
+        ('batch', [Message], credits, wait) |
+        ('tuple', {port: Message}, credits, wait) |
+        ('window', [Message], credits, wait) |
+        ('pull', [Message], credits, wait) | ('landmark', Message, 1, 0.0) |
+        None, where ``wait`` is the head's queue wait (``_observe_wait``).
 
         The push path drains an adaptive micro-batch per wake: up to
         min(queue_depth, batch_max) messages in one channel lock round-trip,
@@ -815,7 +839,7 @@ class Flake:
             for c in self.inputs.values():
                 head = c.peek()
                 if head is not None and not head.is_data():
-                    return ("landmark", c.try_pop(), 1)
+                    return ("landmark", c.try_pop(), 1, 0.0)
             if all(len(c) > 0 for c in self.inputs.values()):
                 tup = {p: c.try_pop() for p, c in self.inputs.items()}
                 if any(m is None for m in tup.values()):   # lost a race
@@ -823,17 +847,17 @@ class Flake:
                         if m is not None:
                             self.inputs[p].unpop(m)  # locked restore
                     return None
-                self._observe_wait(
+                wait = self._observe_wait(
                     min(m.ts for m in tup.values()), len(tup))
-                return ("tuple", tup, len(tup))
+                return ("tuple", tup, len(tup), wait)
             return None
         if isinstance(proto, PullPellet):
             msgs: List[Message] = []
             for c in self.inputs.values():
                 msgs.extend(c.pop_up_to())   # drain all, one lock round-trip
             if msgs:
-                self._observe_wait(msgs[0].ts, len(msgs))
-                return ("pull", msgs, len(msgs))
+                wait = self._observe_wait(msgs[0].ts, len(msgs))
+                return ("pull", msgs, len(msgs), wait)
             return None
         if isinstance(proto, WindowPellet):
             # count window (Fig. 1, P3): gather up to `window` data messages;
@@ -851,14 +875,14 @@ class Flake:
                             # flush partial window, then forward the landmark
                             # (credits include the landmark message itself)
                             self._requeue_landmark_after = m
-                            self._observe_wait(buf[0].ts, len(buf))
-                            return ("window", buf, len(buf) + 1)
-                        return ("landmark", m, 1)
+                            wait = self._observe_wait(buf[0].ts, len(buf))
+                            return ("window", buf, len(buf) + 1, wait)
+                        return ("landmark", m, 1, 0.0)
                     self._window_buf.extend(got)
                     if len(self._window_buf) >= proto.window:
                         buf, self._window_buf = self._window_buf, []
-                        self._observe_wait(buf[0].ts, len(buf))
-                        return ("window", buf, len(buf))
+                        wait = self._observe_wait(buf[0].ts, len(buf))
+                        return ("window", buf, len(buf), wait)
             return None
         # plain push pellet (interleaved merge across ports, Fig. 1, P6):
         # adaptive micro-batch
@@ -899,19 +923,19 @@ class Flake:
                 continue
             head = batch[0]
             if not head.is_data():
-                return ("landmark", head, 1)
+                return ("landmark", head, 1, 0.0)
             if isinstance(head.payload, ArrayBatch):
                 # an upstream stage already stacked this batch: dispatch
                 # the carrier whole — credits/stats counted in rows
                 rows = len(head.payload)
                 self.stats.on_dispatch(rows)
-                self._observe_wait(head.ts, rows)
-                return ("abatch", head, rows)
+                wait = self._observe_wait(head.ts, rows)
+                return ("abatch", head, rows, wait)
             self.stats.on_dispatch(len(batch))
-            self._observe_wait(head.ts, len(batch))
+            wait = self._observe_wait(head.ts, len(batch))
             if len(batch) == 1:
-                return ("msg", batch[0], 1)
-            return ("batch", batch, len(batch))
+                return ("msg", batch[0], 1, wait)
+            return ("batch", batch, len(batch), wait)
         return None
 
     def _batch_limit(self) -> int:
@@ -951,7 +975,8 @@ class Flake:
         return bmax
 
     # -- execution ---------------------------------------------------------------
-    def _run_inline(self, kind: str, item, credits: int) -> None:
+    def _run_inline(self, kind: str, item, credits: int,
+                    wait: float = 0.0) -> None:
         """Run in the dispatch thread, visible to ``_wait_quiescent``.
 
         Without the local in-flight accounting, a sequential/pull pellet
@@ -960,17 +985,19 @@ class Flake:
         """
         self._inflight_inc_local()
         try:
-            self._run_task(kind, item, credits)
+            self._run_task(kind, item, credits, wait)
         finally:
             self._inflight_dec_local()
 
-    def _submit(self, kind: str, item, credits: int) -> None:
+    def _submit(self, kind: str, item, credits: int,
+                wait: float = 0.0) -> None:
         if not self._sem.acquire(timeout=30):
             # no instance slot (cores may be 0) — run inline as fallback
-            self._run_inline(kind, item, credits)
+            self._run_inline(kind, item, credits, wait)
             return
         self._inflight_inc_local()
-        fut = self._pool.submit(self._run_pooled, kind, item, credits)
+        fut = self._pool.submit(self._run_pooled, kind, item, credits,
+                                wait=wait)
         if self.speculative_timeout is not None and kind == "msg":
             with self._spec_cond:
                 self._spec_q.append(
@@ -1012,93 +1039,100 @@ class Flake:
         self._pool.submit(self._run_pooled, "msg", item, credits, False)
 
     def _run_pooled(self, kind: str, item, credits: int,
-                    release_slot: bool = True) -> None:
+                    release_slot: bool = True, wait: float = 0.0) -> None:
         try:
-            self._run_task(kind, item, credits)
+            self._run_task(kind, item, credits, wait)
         finally:
             if release_slot:
                 self._sem.release()
             self._inflight_dec_local()
 
-    def _run_task(self, kind: str, item, credits: int) -> None:
+    def _run_task(self, kind: str, item, credits: int,
+                  wait: float = 0.0) -> None:
         with self._pellet_lock:
             proto = self._proto
             version = self.version
-        t0 = time.time()
         outputs: List[Message] = []
         seq_for_dedup = item.seq if isinstance(item, Message) else None
-        try:
-            handled = False
-            remote = self.remote
-            if remote is not None and kind in ("msg", "batch", "abatch") \
-                    and self._remote_eligible(proto):
-                res = self._remote_task(remote, proto, kind, item)
-                if res is not None:
-                    outputs = res
-                    handled = True
-            if handled:
-                pass
-            elif kind == "msg":
-                if seq_for_dedup is not None and self.speculative_timeout is not None:
-                    with self._inflight_cond:
-                        if seq_for_dedup in self._done_seqs:
-                            return  # duplicate speculative task lost the race
-                if self._chaos is not None:
-                    self._chaos.check_one(item.payload)
-                result = proto.compute(item.payload)
-                outputs = self._wrap(result, item)
-            elif kind == "batch":
-                # micro-batch of data messages from ONE channel: one
-                # compute_batch call, per-message lineage/wrap preserved.
-                # With the array opt-in, stackable payloads take the
-                # columnar fast path instead (one ArrayBatch carrier out).
-                # An armed chaos rule forces the row-wise path so a
-                # poison row fails alone instead of sinking the batch.
-                outputs = None
-                if self.batch_array and self._chaos is None:
-                    outputs = self._array_outputs(proto, msgs=item)
-                if outputs is None:
-                    outputs = self._batch_outputs(proto, item)
-            elif kind == "abatch":
-                # an ArrayBatch carrier: one compute_array call over the
-                # stacked array, no unstack between vectorized stages.  If
-                # the pellet declines the array path, degrade the carrier
-                # to the exact row-wise batched semantics.
-                ab = item.payload
-                outputs = None
-                if self._chaos is None:
-                    outputs = self._array_outputs(proto, ab=ab)
-                if outputs is None:
-                    outputs = self._batch_outputs(
-                        proto, ab.to_messages(port=item.port))
-            elif kind == "tuple":
-                payloads = {p: m.payload for p, m in item.items()}
-                anchor = next(iter(item.values()))
-                result = proto.compute(payloads)
-                outputs = self._wrap(result, anchor)
-            elif kind == "window":
-                payloads = [m.payload for m in item]
-                result = proto.compute(payloads)
-                outputs = self._wrap(result, item[0])
-            elif kind == "pull":
-                emitted: List[Message] = []
-                anchor = item[0]
+        err: Optional[Exception] = None
+        # the span covers exactly the service time the histogram observes
+        with self._span("floe.dispatch", kind=kind, rows=credits):
+            t0 = time.time()
+            try:
+                handled = False
+                remote = self.remote
+                if remote is not None and kind in ("msg", "batch", "abatch") \
+                        and self._remote_eligible(proto):
+                    res = self._remote_task(remote, proto, kind, item)
+                    if res is not None:
+                        outputs = res
+                        handled = True
+                if handled:
+                    pass
+                elif kind == "msg":
+                    if seq_for_dedup is not None and self.speculative_timeout is not None:
+                        with self._inflight_cond:
+                            if seq_for_dedup in self._done_seqs:
+                                return  # duplicate speculative task lost the race
+                    if self._chaos is not None:
+                        self._chaos.check_one(item.payload)
+                    result = proto.compute(item.payload)
+                    outputs = self._wrap(result, item)
+                elif kind == "batch":
+                    # micro-batch of data messages from ONE channel: one
+                    # compute_batch call, per-message lineage/wrap preserved.
+                    # With the array opt-in, stackable payloads take the
+                    # columnar fast path instead (one ArrayBatch carrier out).
+                    # An armed chaos rule forces the row-wise path so a
+                    # poison row fails alone instead of sinking the batch.
+                    outputs = None
+                    if self.batch_array and self._chaos is None:
+                        outputs = self._array_outputs(proto, msgs=item)
+                    if outputs is None:
+                        outputs = self._batch_outputs(proto, item)
+                elif kind == "abatch":
+                    # an ArrayBatch carrier: one compute_array call over the
+                    # stacked array, no unstack between vectorized stages.  If
+                    # the pellet declines the array path, degrade the carrier
+                    # to the exact row-wise batched semantics.
+                    ab = item.payload
+                    outputs = None
+                    if self._chaos is None:
+                        outputs = self._array_outputs(proto, ab=ab)
+                    if outputs is None:
+                        outputs = self._batch_outputs(
+                            proto, ab.to_messages(port=item.port))
+                elif kind == "tuple":
+                    payloads = {p: m.payload for p, m in item.items()}
+                    anchor = next(iter(item.values()))
+                    result = proto.compute(payloads)
+                    outputs = self._wrap(result, anchor)
+                elif kind == "window":
+                    payloads = [m.payload for m in item]
+                    result = proto.compute(payloads)
+                    outputs = self._wrap(result, item[0])
+                elif kind == "pull":
+                    emitted: List[Message] = []
+                    anchor = item[0]
 
-                def emit(payload, *, port: str = None, key: Any = None,
-                         landmark: bool = False):
-                    m = anchor.derive(payload, key=key,
-                                      port=port or proto.out_ports[0])
-                    m.landmark = landmark
-                    emitted.append(m)
+                    def emit(payload, *, port: str = None, key: Any = None,
+                             landmark: bool = False):
+                        m = anchor.derive(payload, key=key,
+                                          port=port or proto.out_ports[0])
+                        m.landmark = landmark
+                        emitted.append(m)
 
-                with self._state_lock:
-                    st = self.state
-                new_state = proto.compute(iter(item), emit, st)
-                with self._state_lock:
-                    self.state = new_state
-                outputs = emitted
-        except Exception as e:  # pellet error: count and drop (log upstream)
-            lat = time.time() - t0
+                    with self._state_lock:
+                        st = self.state
+                    new_state = proto.compute(iter(item), emit, st)
+                    with self._state_lock:
+                        self.state = new_state
+                    outputs = emitted
+            except Exception as e:  # pellet error: count and drop (log upstream)
+                err = e
+            t1 = time.time()
+        if err is not None:
+            lat = t1 - t0
             self.stats.on_process(lat, n=credits)
             if self._tele_service is not None:
                 self._tele_service.observe(lat / max(credits, 1), n=credits)
@@ -1107,8 +1141,8 @@ class Flake:
                 # them (returns True = handled); default is drop-and-log
                 faults = self.engine._faults
                 if faults is None or not faults.on_task_error(
-                        self, kind, item, e):
-                    self.engine._record_error(self.name, e)
+                        self, kind, item, err):
+                    self.engine._record_error(self.name, err)
                 self.engine._inflight_dec(credits)
             return
         if seq_for_dedup is not None and self.speculative_timeout is not None:
@@ -1116,14 +1150,14 @@ class Flake:
                 if seq_for_dedup in self._done_seqs:
                     return  # another speculative copy already delivered
                 self._done_seqs.add(seq_for_dedup)
-        t1 = time.time()
         self.stats.on_process(t1 - t0, n=credits)
         if self._tele_service is not None:
             self._tele_service.observe((t1 - t0) / max(credits, 1),
                                        n=credits)
-            self._record_spans(kind, item, t0, t1)
+            self._record_spans(kind, item, t0, t1, wait)
         try:
-            self._route_many(outputs)
+            with self._span("floe.route"):
+                self._route_many(outputs)
             self.stats.on_emit(_rows_total(outputs))
             # forward a landmark that flushed a partial window
             lm = getattr(self, "_requeue_landmark_after", None)
@@ -1141,11 +1175,11 @@ class Flake:
             if self.engine is not None:
                 self.engine._inflight_dec(credits)
 
-    def _record_spans(self, kind: str, item, t0: float, t1: float) -> None:
+    def _record_spans(self, kind: str, item, t0: float, t1: float,
+                      wait: float) -> None:
         """One span per distinct traced context in the dispatched work
-        (rows sharing a trace aggregate into a single span).  Only runs
-        when the tracer is sampling — checked by the caller via
-        ``tracer.active`` before paying the per-message meta scan."""
+        (rows sharing a trace aggregate into a single span), each with the
+        dispatch's queue wait.  Only runs when the tracer is sampling."""
         tele = self._tele
         if tele is None or not tele.tracer.active:
             return
@@ -1176,7 +1210,8 @@ class Flake:
                 if self.engine is not None else "local")
         for ctx, rows in ctxs.values():
             tele.tracer.record_span(ctx, stage=self.name, host=host,
-                                    rows=rows, t_start=t0, t_end=t1)
+                                    rows=rows, t_start=t0, t_end=t1,
+                                    queue_wait=wait)
 
     # -- remote compute offload (process-backed hosts) ------------------------
     def _remote_eligible(self, proto: Pellet) -> bool:
@@ -1898,12 +1933,14 @@ class Coordinator:
                 ctx = tele.tracer.maybe_trace()
                 if ctx is not None:
                     msg.meta[TRACE_KEY] = ctx
-        with self._inject_lock:
-            self.flakes[flake_name].enqueue(port, msg)
-            if self._faults is not None:
-                self._faults.journal_rows(
-                    flake_name, port, (payload,),
-                    None if key is None else (key,))
+        with (span("floe.inject", stage=flake_name, rows=1)
+              if tele.enabled else NO_SPAN):
+            with self._inject_lock:
+                self.flakes[flake_name].enqueue(port, msg)
+                if self._faults is not None:
+                    self._faults.journal_rows(
+                        flake_name, port, (payload,),
+                        None if key is None else (key,))
 
     def inject_many(self, flake_name: str, payloads: List[Any], *,
                     port: str = "in",
@@ -1928,54 +1965,56 @@ class Coordinator:
             raise ValueError(
                 f"inject_many: {len(keys)} keys for {len(payloads)} payloads")
         tele = self.telemetry
-        tracing = tele.enabled and tele.tracer.active
-        if tele.enabled:
-            tele.injected.inc(len(payloads))
-        if stacked and payloads:
-            traces = None
+        with (span("floe.inject", stage=flake_name, rows=len(payloads))
+              if tele.enabled else NO_SPAN):
+            tracing = tele.enabled and tele.tracer.active
+            if tele.enabled:
+                tele.injected.inc(len(payloads))
+            if stacked and payloads:
+                traces = None
+                if tracing:
+                    traces = [tele.tracer.maybe_trace() for _ in payloads]
+                    if not any(t is not None for t in traces):
+                        traces = None
+                ab = ArrayBatch.try_stack(
+                    payloads, seqs=[_next_seq() for _ in payloads],
+                    keys=keys, traces=traces)
+                if ab is not None:
+                    if tele.enabled:
+                        tele.stacked_injections.inc()
+                    with self._inject_lock:
+                        self.flakes[flake_name].enqueue(
+                            port, Message(payload=ab))
+                        if self._faults is not None:
+                            self._faults.journal_rows(
+                                flake_name, port, payloads, keys)
+                    return
+                # ragged payloads: fall through to the per-message path (any
+                # contexts handed out above are reused row-aligned below)
+                if traces is not None:
+                    msgs = [Message(payload=p,
+                                    key=keys[i] if keys is not None else None)
+                            for i, p in enumerate(payloads)]
+                    for m, ctx in zip(msgs, traces):
+                        if ctx is not None:
+                            m.meta[TRACE_KEY] = ctx
+                    with self._inject_lock:
+                        self.flakes[flake_name].enqueue_many(port, msgs)
+                        if self._faults is not None:
+                            self._faults.journal_rows(
+                                flake_name, port, payloads, keys)
+                    return
+            msgs = [Message(payload=p, key=keys[i] if keys is not None else None)
+                    for i, p in enumerate(payloads)]
             if tracing:
-                traces = [tele.tracer.maybe_trace() for _ in payloads]
-                if not any(t is not None for t in traces):
-                    traces = None
-            ab = ArrayBatch.try_stack(
-                payloads, seqs=[_next_seq() for _ in payloads],
-                keys=keys, traces=traces)
-            if ab is not None:
-                if tele.enabled:
-                    tele.stacked_injections.inc()
-                with self._inject_lock:
-                    self.flakes[flake_name].enqueue(
-                        port, Message(payload=ab))
-                    if self._faults is not None:
-                        self._faults.journal_rows(
-                            flake_name, port, payloads, keys)
-                return
-            # ragged payloads: fall through to the per-message path (any
-            # contexts handed out above are reused row-aligned below)
-            if traces is not None:
-                msgs = [Message(payload=p,
-                                key=keys[i] if keys is not None else None)
-                        for i, p in enumerate(payloads)]
-                for m, ctx in zip(msgs, traces):
+                for m in msgs:
+                    ctx = tele.tracer.maybe_trace()
                     if ctx is not None:
                         m.meta[TRACE_KEY] = ctx
-                with self._inject_lock:
-                    self.flakes[flake_name].enqueue_many(port, msgs)
-                    if self._faults is not None:
-                        self._faults.journal_rows(
-                            flake_name, port, payloads, keys)
-                return
-        msgs = [Message(payload=p, key=keys[i] if keys is not None else None)
-                for i, p in enumerate(payloads)]
-        if tracing:
-            for m in msgs:
-                ctx = tele.tracer.maybe_trace()
-                if ctx is not None:
-                    m.meta[TRACE_KEY] = ctx
-        with self._inject_lock:
-            self.flakes[flake_name].enqueue_many(port, msgs)
-            if self._faults is not None:
-                self._faults.journal_rows(flake_name, port, payloads, keys)
+            with self._inject_lock:
+                self.flakes[flake_name].enqueue_many(port, msgs)
+                if self._faults is not None:
+                    self._faults.journal_rows(flake_name, port, payloads, keys)
 
     def inject_landmark(self, flake_name: str, tag: Any = None,
                         port: str = "in") -> None:

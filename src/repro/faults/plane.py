@@ -357,7 +357,7 @@ class FaultPlane:
                     # crash semantics: a FRESH pellet instance (in-memory
                     # instance state is what the crash destroyed; durable
                     # state comes back from the checkpoint plane)
-                    flake._proto = flake.factory()
+                    flake._proto = flake._bind(flake.factory())
                     flake.version += 1
                 try:
                     old.teardown()

@@ -53,5 +53,5 @@ def cluster_distances(x: jnp.ndarray, centroids: jnp.ndarray, *,
                   pl.BlockSpec((K, D), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block_b, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name="cluster_distance",
     )(x, centroids)

@@ -119,6 +119,6 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, 1, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="decode_attention",
     )(lengths.astype(jnp.int32), qf, kf, vf)
     return out.reshape(B, H, hd)

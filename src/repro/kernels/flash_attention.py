@@ -133,6 +133,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

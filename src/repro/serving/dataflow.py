@@ -26,6 +26,14 @@ naturally waits for all generations, backpressure applies, and a
 checkpoint's consistent cut always contains either the pending tick or no
 live slots.  At most one tick is in flight (``tick_pending``).
 
+Bound to a running stage (the engine calls ``bind_telemetry``), prefill
+and decode count their work in the coordinator's ``MetricsRegistry``
+(``floe_prefill_tokens_total``/``floe_prefill_positions_total``,
+``floe_decode_steps_total``/``floe_decode_tokens_total``, and the
+``floe_decode_tick_wait_seconds`` histogram) and open ``floe.prefill.*`` /
+``floe.decode.*`` profiler spans around their device launches, host waits
+and bookkeeping.  Unbound (telemetry off), both are no-ops.
+
 Every response dict carries ``version`` — the model version of the decode
 weights at completion time (the paper's update-landmark made visible to
 clients), plus ``t_sub``/``t_first``/``t_done`` for TTFT/TPOT accounting.
@@ -41,6 +49,7 @@ import numpy as np
 from ..api.builder import Flow
 from ..core.pellet import Drop, KeyedEmit, PushPellet
 from ..kernels.ops import pallas_interpret
+from ..telemetry.tracing import NO_SPAN, span
 from . import kv
 from .kv import LMSpec, init_params
 from .scheduler import Scheduler, make_request
@@ -56,7 +65,27 @@ def _np32(x) -> np.ndarray:
     return np.asarray(x, dtype=np.int32)
 
 
-class PrefillPellet(PushPellet):
+class _StagePellet(PushPellet):
+    """A serving pellet that counts and spans its work once the engine
+    binds it to a stage; unbound, every instrumentation site is a no-op."""
+
+    #: the stage this instance serves, set by ``bind_telemetry``
+    _stage: Optional[str] = None
+
+    def bind_telemetry(self, telemetry: Any, stage: str) -> None:
+        self._stage = stage
+
+    def _span(self, name: str, **meta: Any):
+        if self._stage is None:
+            return NO_SPAN
+        return span(name, stage=self._stage, **meta)
+
+
+def _counter(registry: Any, name: str, help_: str, stage: str):
+    return registry.counter(name, help_, ("stage",)).labels(stage=stage)
+
+
+class PrefillPellet(_StagePellet):
     """Vectorized prompt pass: admission columns in, KV + first token out.
 
     Stateless (weights are construction-time constants), so the engine is
@@ -67,6 +96,7 @@ class PrefillPellet(PushPellet):
 
     in_ports = ("in",)
     out_ports = ("out",)
+    _tokens = _positions = None      # counters, once bound
 
     def __init__(self, params: Dict[str, Any], spec: LMSpec, *,
                  version: int = 0, ref_path: bool = False):
@@ -76,20 +106,38 @@ class PrefillPellet(PushPellet):
         self.ref_path = bool(ref_path)
         self.interpret = pallas_interpret()
 
+    def bind_telemetry(self, telemetry: Any, stage: str) -> None:
+        super().bind_telemetry(telemetry, stage)
+        registry = telemetry.registry
+        self._tokens = _counter(
+            registry, "floe_prefill_tokens_total",
+            "Real prompt tokens prefilled, by stage.", stage)
+        self._positions = _counter(
+            registry, "floe_prefill_positions_total",
+            "Prompt positions prefill computed (batch x padded prompt "
+            "length), by stage.", stage)
+
     def compute_array(self, cols: Any) -> Any:
         if not isinstance(cols, dict) or "tokens" not in cols:
             return NotImplemented
-        tokens = jnp.asarray(_np32(cols["tokens"]))          # (B, max_prompt)
-        lengths = jnp.asarray(_np32(cols["length"]))         # (B,)
-        if self.ref_path:
-            logits, kc, vc = kv.prefill_ref(
-                self.params, tokens, lengths, spec=self.spec)
-        else:
-            logits, kc, vc = kv.prefill(
-                self.params, tokens, lengths, spec=self.spec,
-                interpret=self.interpret)
-        tok0 = _np32(kv.greedy(logits))                      # (B,)
-        B = int(tokens.shape[0])
+        host_tokens = _np32(cols["tokens"])                  # (B, max_prompt)
+        host_lengths = _np32(cols["length"])                 # (B,)
+        B = int(host_tokens.shape[0])
+        with self._span("floe.prefill.launch", rows=B):
+            tokens = jnp.asarray(host_tokens)
+            lengths = jnp.asarray(host_lengths)
+            if self.ref_path:
+                logits, kc, vc = kv.prefill_ref(
+                    self.params, tokens, lengths, spec=self.spec)
+            else:
+                logits, kc, vc = kv.prefill(
+                    self.params, tokens, lengths, spec=self.spec,
+                    interpret=self.interpret)
+        with self._span("floe.prefill.sync", rows=B):
+            tok0 = _np32(kv.greedy(logits))                  # (B,)
+        if self._tokens is not None:
+            self._tokens.inc(int(host_lengths.sum()))
+            self._positions.inc(host_tokens.size)
         return {
             "rid": _np32(cols["rid"]), "slot": _np32(cols["slot"]),
             "length": _np32(cols["length"]), "budget": _np32(cols["budget"]),
@@ -110,7 +158,7 @@ class PrefillPellet(PushPellet):
         return {k_: v_[0] for k_, v_ in out.items()}
 
 
-class DecodePellet(PushPellet):
+class DecodePellet(_StagePellet):
     """Continuously-batched decode: splice carriers in, responses out.
 
     Holds the whole decode-tier working set as ``__floe_state__`` instance
@@ -130,6 +178,11 @@ class DecodePellet(PushPellet):
     sequential = True
     __floe_state__ = ("k", "v", "lengths", "last_tok", "live", "meta",
                       "tick_pending", "n_steps", "n_spliced")
+    _steps = _tokens = _tick_wait = None      # counters, once bound
+    #: ``perf_counter`` when the pending tick was emitted; instance-only
+    #: (not checkpointed), so the first tick after a restore or a swap is
+    #: not observed
+    _tick_t: Optional[float] = None
 
     def __init__(self, params: Dict[str, Any], spec: LMSpec, *,
                  n_slots: int = 4, version: int = 0, ref_path: bool = False):
@@ -152,6 +205,21 @@ class DecodePellet(PushPellet):
         self.tick_pending = False
         self.n_steps = 0
         self.n_spliced = 0
+
+    def bind_telemetry(self, telemetry: Any, stage: str) -> None:
+        super().bind_telemetry(telemetry, stage)
+        registry = telemetry.registry
+        self._steps = _counter(
+            registry, "floe_decode_steps_total",
+            "Decode steps taken over the slot batch, by stage.", stage)
+        self._tokens = _counter(
+            registry, "floe_decode_tokens_total",
+            "Live slots advanced by decode steps (tokens generated), by "
+            "stage.", stage)
+        self._tick_wait = registry.histogram(
+            "floe_decode_tick_wait_seconds",
+            "Time from a decode tick's emission to the start of the step "
+            "it drives, by stage.", ("stage",)).labels(stage=stage)
 
     # -- checkpoint / hot-swap state -----------------------------------------
     def get_state(self) -> Dict[str, Any]:
@@ -188,14 +256,15 @@ class DecodePellet(PushPellet):
         rows = int(np.asarray(cols["slot"]).shape[0])
         emits: List[List[Any]] = [[] for _ in range(rows)]
         slots = np.asarray(cols["slot"], dtype=np.int64)
-        self.k = self.k.at[:, slots].set(
-            jnp.moveaxis(jnp.asarray(cols["k"]), 0, 1))
-        self.v = self.v.at[:, slots].set(
-            jnp.moveaxis(jnp.asarray(cols["v"]), 0, 1))
-        for i in range(rows):
-            self._admit_row({name: col[i] for name, col in cols.items()},
-                            emits[i], spliced=True)
-        self._maybe_tick(emits[-1])
+        with self._span("floe.decode.splice", rows=rows):
+            self.k = self.k.at[:, slots].set(
+                jnp.moveaxis(jnp.asarray(cols["k"]), 0, 1))
+            self.v = self.v.at[:, slots].set(
+                jnp.moveaxis(jnp.asarray(cols["v"]), 0, 1))
+            for i in range(rows):
+                self._admit_row({name: col[i] for name, col in cols.items()},
+                                emits[i], spliced=True)
+            self._maybe_tick(emits[-1])
         return emits
 
     def compute(self, payload: Any) -> Any:
@@ -238,22 +307,32 @@ class DecodePellet(PushPellet):
         """One decode_attention step over the full slot batch."""
         if not self.live.any():
             return
+        if self._tick_t is not None:
+            self._tick_wait.observe(time.perf_counter() - self._tick_t)
+            self._tick_t = None
         step = kv.decode_step_ref if self.ref_path else kv.decode_step
         kwargs = {} if self.ref_path else {"interpret": self.interpret}
-        logits, self.k, self.v = step(
-            self.params, self.k, self.v, jnp.asarray(self.lengths),
-            jnp.asarray(self.last_tok), spec=self.spec, **kwargs)
-        nxt = _np32(kv.greedy(logits))
+        with self._span("floe.decode.launch"):
+            logits, self.k, self.v = step(
+                self.params, self.k, self.v, jnp.asarray(self.lengths),
+                jnp.asarray(self.last_tok), spec=self.spec, **kwargs)
+        with self._span("floe.decode.sync"):
+            nxt = _np32(kv.greedy(logits))
         self.n_steps += 1
-        for s in np.nonzero(self.live)[0]:
-            s = int(s)
-            self.lengths[s] += 1
-            tok = int(nxt[s])
-            m = self.meta[s]
-            m["tokens"].append(tok)
-            self.last_tok[s] = tok
-            if len(m["tokens"]) >= m["budget"]:
-                self._finish(s, emits)
+        live = np.nonzero(self.live)[0]
+        if self._steps is not None:
+            self._steps.inc()
+            self._tokens.inc(len(live))
+        with self._span("floe.decode.bookkeep"):
+            for s in live:
+                s = int(s)
+                self.lengths[s] += 1
+                tok = int(nxt[s])
+                m = self.meta[s]
+                m["tokens"].append(tok)
+                self.last_tok[s] = tok
+                if len(m["tokens"]) >= m["budget"]:
+                    self._finish(s, emits)
 
     def _finish(self, s: int, emits: List[Any]) -> None:
         m = self.meta.pop(s)
@@ -270,6 +349,8 @@ class DecodePellet(PushPellet):
     def _maybe_tick(self, emits: List[Any]) -> None:
         if self.live.any() and not self.tick_pending:
             self.tick_pending = True
+            if self._tick_wait is not None:
+                self._tick_t = time.perf_counter()
             emits.append(KeyedEmit(TICK, port="tick"))
 
 

@@ -10,7 +10,10 @@ the three observability surfaces:
   errors, and cluster ledger events (JSONL-exportable);
 * :class:`~repro.telemetry.tracing.Tracer` — sampled per-message dataflow
   traces (a span per flake hop, surviving ArrayBatch stacking, cross-host
-  transport, migration, checkpoint/restore).
+  transport, migration, checkpoint/restore);
+* :func:`~repro.telemetry.tracing.span` — ``floe.*`` spans on the
+  profiler's clock around dispatch, routing, injection and the serving
+  plane's device launches and waits.
 
 The facade also pre-declares the engine metric families so instrumentation
 sites grab label children once (at flake construction) and pay a single
@@ -29,13 +32,14 @@ from .events import EventBus
 from .export import parse_prometheus, render_prometheus
 from .registry import LATENCY_BUCKETS, Counter, Family, Gauge, Histogram, \
     MetricsRegistry
-from .tracing import TRACE_KEY, Tracer, make_context, trace_of
+from .tracing import NO_SPAN, TRACE_KEY, Tracer, make_context, span, \
+    trace_of
 
 __all__ = [
     "Telemetry", "MetricsRegistry", "EventBus", "Tracer",
     "Counter", "Gauge", "Histogram", "Family", "LATENCY_BUCKETS",
     "render_prometheus", "parse_prometheus",
-    "TRACE_KEY", "make_context", "trace_of",
+    "TRACE_KEY", "make_context", "trace_of", "span", "NO_SPAN",
 ]
 
 #: queue-wait histograms see longer tails than service times (a message can

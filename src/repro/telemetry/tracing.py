@@ -12,15 +12,25 @@ Sampling is the cost knob: with ``sample=0.0`` (default) the tracer is
 completely inert — injection does not allocate a context and the engine's
 span-recording branches short-circuit on ``tracer.active``.  At
 ``sample=1.0`` every injected message is traced.
+
+The second kind of span lives on the profiler's clock: ``span`` wraps a
+``jax.profiler.TraceAnnotation`` named ``floe.*`` whose keyword metadata
+(``stage=``, ``rows=`` ...) lands as event stats, so a device trace can say
+which host code was running while the device sat idle.  Host thread names
+do not reach the profiler's file, so the stage always rides the metadata.
+Sites build one only through a bound telemetry handle; with telemetry off
+they use ``NO_SPAN`` and build nothing.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import random
 import threading
-import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 #: meta key under which the trace context rides a Message
 TRACE_KEY = "trace"
@@ -28,10 +38,19 @@ TRACE_KEY = "trace"
 _trace_ids = itertools.count(1)
 
 
+#: the shared no-op context a site enters when telemetry is off
+NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **meta: Any) -> TraceAnnotation:
+    """A profiler span: free of effect unless a trace is being recorded,
+    ~1 us to build and enter on a host CPU when not."""
+    return TraceAnnotation(name, **meta)
+
+
 def make_context(tid: Optional[int] = None) -> Dict[str, Any]:
     """A fresh trace context (the dict stored at ``meta['trace']``)."""
-    return {"id": tid if tid is not None else next(_trace_ids),
-            "t0": time.time()}
+    return {"id": tid if tid is not None else next(_trace_ids)}
 
 
 class Tracer:

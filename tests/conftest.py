@@ -14,18 +14,19 @@ cache.
   (``repro.launch.use_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` or the
   checkout's ``.jax_cache/``): the model/kernel smoke tests are dominated
   by XLA compilation, so warm reruns and cached CI runs cut minutes.
+
+Helpers the tests import live in ``testkit.py``, not here: the benchmark's
+own tests (``bench/tests``) are collected in the same session with a
+``conftest`` of their own, so ``from conftest import ...`` would be
+ambiguous.
 """
-import importlib.util
 import math
 import os
-import pathlib
 import signal
-import sys
 import threading
 
 import pytest
 
-ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 # -- CPU platform + JAX persistent compilation cache (before jax imports) ----
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -79,32 +80,3 @@ def pytest_runtest_call(item):
     finally:
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old_handler)
-
-
-# -- polling helpers (replace sleep-based waits in dataflow tests) ------------
-
-def wait_until(predicate, *, timeout: float = 10.0,
-               interval: float = 0.005) -> bool:
-    """Poll ``predicate`` until truthy or ``timeout``; returns the verdict.
-
-    Use instead of fixed ``time.sleep`` so tests advance the moment the
-    engine reaches the awaited state.
-    """
-    import time
-    deadline = time.time() + timeout
-    while time.time() < deadline:
-        if predicate():
-            return True
-        time.sleep(interval)
-    return bool(predicate())
-
-
-def load_chip_smoke():
-    """The repo-root ``chip_smoke.py`` as a module (loaded once)."""
-    if "chip_smoke" not in sys.modules:
-        spec = importlib.util.spec_from_file_location(
-            "chip_smoke", ROOT / "chip_smoke.py")
-        mod = importlib.util.module_from_spec(spec)
-        sys.modules["chip_smoke"] = mod
-        spec.loader.exec_module(mod)
-    return sys.modules["chip_smoke"]

@@ -14,7 +14,7 @@ import time
 import numpy as np
 import pytest
 
-from conftest import wait_until
+from testkit import wait_until
 from repro.api import Flow
 from repro.core import (ArrayBatch, Coordinator, FloeGraph, FnPellet,
                         Message, PushPellet, WindowPellet, stable_hash)

@@ -11,7 +11,7 @@ import threading
 
 import pytest
 
-from conftest import wait_until
+from testkit import wait_until
 from repro.api import Flow
 from repro.api.errors import CompositionError
 from repro.core import (Coordinator, Drop, FloeGraph, FnMapper, FnPellet,

@@ -18,7 +18,7 @@ from repro import (ClusterError, ClusterManager, ClusterSpec, CompositionError,
 from repro.adaptation import AdaptationController, DynamicAdaptation
 from repro.cluster import LoopbackTransport, SerializingTransport
 
-from conftest import wait_until
+from testkit import wait_until
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from repro.cluster.manager import ClusterManager
 from repro.cluster.workers import ProcessBackend, ShmRing
 from repro.faults import CheckpointPolicy
 
-from conftest import wait_until
+from testkit import wait_until
 
 
 # -- module-level pellet functions (spawn workers unpickle by reference) ----

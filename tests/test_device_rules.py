@@ -20,7 +20,7 @@ import sys
 
 import jax
 import pytest
-from conftest import ROOT, load_chip_smoke
+from testkit import ROOT, load_chip_smoke
 
 from repro.cluster.workers.handle import WorkerHandle
 from repro.kernels import ops

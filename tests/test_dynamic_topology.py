@@ -6,7 +6,7 @@ import time
 
 import pytest
 
-from conftest import wait_until
+from testkit import wait_until
 from repro import (ClusterManager, ClusterSpec, Flow, FnPellet, PullPellet,
                    PushPellet, RecompositionError, Session, WindowPellet)
 from repro.checkpoint import read_floe_meta

@@ -4,7 +4,7 @@ import time
 
 import pytest
 
-from conftest import wait_until
+from testkit import wait_until
 from repro.core import (Coordinator, FloeGraph, FnPellet, Message, PullPellet,
                         PushPellet)
 

@@ -9,17 +9,19 @@ Covers PR 8's tentpole and satellites:
   numerics *through the dataflow*, checkpoint→kill→restore of in-flight
   generations, and zero-loss live weight hot-swap with version tags.
 """
+import contextlib
 import pickle
 import time
 
 import numpy as np
 import pytest
-from conftest import wait_until
+from testkit import wait_until
 
 from repro import Flow, FnPellet, PushPellet, Session
 from repro.core.arraybatch import ArrayBatch
-from repro.serving import (LMSpec, Scheduler, build_serving_flow,
+from repro.serving import (LMSpec, Scheduler, build_serving_flow, kv,
                            make_request, swapped_flow)
+from repro.telemetry import parse_prometheus
 
 #: one tiny geometry shared by every dataflow test — jit caches per
 #: (spec, shapes), so reuse keeps the suite to a handful of compiles
@@ -197,6 +199,126 @@ class TestServingPlane:
         # sharing the slot batch must cut that down
         assert decode.n_steps < 18
 
+    def test_counters_census(self):
+        """The serving counters agree with what the flow did: a tick-wait
+        sample per decode step, one token per live slot a step, every
+        real prompt token and every padded position prefill computed —
+        read from the Prometheus text an operator scrapes."""
+        prompts = [[1 + i] * (1 + i % 5) for i in range(7)]
+        budgets = [2 + i % 4 for i in range(7)]
+        flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=4,
+                                  seed=0)
+        with flow.session() as s:
+            s.inject_many("sched", [make_request(i, p, max_new=b)
+                                    for i, (p, b) in
+                                    enumerate(zip(prompts, budgets))])
+            resp = _responses(s.results(timeout=90))
+            decode = s.coordinator.flakes["decode"]._proto
+            max_prompt = s.coordinator.flakes["sched"]._proto.max_prompt
+            text = s.telemetry.prometheus()
+        series = parse_prometheus(text)
+
+        def value(name, stage):
+            hits = [v for labels, v in series[name]
+                    if labels == {"stage": stage}]
+            assert len(hits) == 1, (name, series.get(name))
+            return hits[0]
+
+        assert [r["n_new"] for r in resp] == budgets
+        assert decode.n_steps > 0
+        assert value("floe_decode_steps_total", "decode") == decode.n_steps
+        assert value("floe_decode_tick_wait_seconds_count", "decode") == \
+            decode.n_steps
+        assert value("floe_decode_tick_wait_seconds_sum", "decode") > 0.0
+        assert value("floe_decode_tokens_total", "decode") == \
+            sum(r["n_new"] - 1 for r in resp)
+        assert value("floe_prefill_tokens_total", "prefill") == \
+            sum(min(len(p), max_prompt) for p in prompts)
+        # every request is prefilled once, in a batch padded to max_prompt
+        assert value("floe_prefill_positions_total", "prefill") == \
+            len(prompts) * max_prompt
+
+    def test_profiler_spans_nest_in_dispatch(self, tmp_path):
+        """Under the profiler, every decode step leaves one launch, one
+        sync and one bookkeeping span tagged ``stage="decode"``, each
+        inside a ``floe.dispatch`` of the decode stage on its thread."""
+        import jax
+        from jax.profiler import ProfileData
+        flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=4,
+                                  seed=0)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        with flow.session() as s:
+            jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+            try:
+                s.inject_many("sched", [make_request(i, [2 + i, 3],
+                                                     max_new=3 + i)
+                                        for i in range(4)])
+                resp = _responses(s.results(timeout=90))
+            finally:
+                jax.profiler.stop_trace()
+            steps = s.coordinator.flakes["decode"]._proto.n_steps
+        assert len(resp) == 4 and steps > 0
+        path = next(tmp_path.rglob("*.xplane.pb"))
+        spans = []                  # (name, stage, start, end, line)
+        for plane in ProfileData.from_file(str(path)).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for li, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("floe."):
+                        stage = dict(e.stats).get("stage")
+                        spans.append((e.name, stage, e.start_ns,
+                                      e.start_ns + e.duration_ns,
+                                      (plane.name, li)))
+        dispatches = [sp for sp in spans
+                      if sp[0] == "floe.dispatch" and sp[1] == "decode"]
+        for name in ("floe.decode.launch", "floe.decode.sync",
+                     "floe.decode.bookkeep"):
+            mine = [sp for sp in spans if sp[0] == name]
+            assert len(mine) == steps, (name, len(mine), steps)
+            assert all(sp[1] == "decode" for sp in mine)
+            for _, _, start, end, where in mine:
+                assert any(d[4] == where and d[2] <= start and end <= d[3]
+                           for d in dispatches), (name, start)
+        names = {sp[0] for sp in spans}
+        assert {"floe.inject", "floe.route", "floe.decode.splice",
+                "floe.prefill.launch", "floe.prefill.sync"} <= names
+
+    def test_telemetry_off_builds_no_span_and_counts_nothing(
+            self, monkeypatch):
+        import repro.core.engine as engine_mod
+        import repro.serving.dataflow as dataflow_mod
+        built = []
+
+        def counting_span(name, **meta):
+            built.append(name)
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(engine_mod, "span", counting_span)
+        monkeypatch.setattr(dataflow_mod, "span", counting_span)
+        seen = {}
+        for on in (True, False):
+            built.clear()
+            flow = build_serving_flow(spec=SPEC, n_slots=2,
+                                      default_budget=3, seed=0)
+            with flow.session(telemetry=on) as s:
+                s.inject_many("sched", [make_request(i, [1 + i, 2],
+                                                     max_new=3)
+                                        for i in range(3)])
+                assert len(_responses(s.results(timeout=90))) == 3
+                decode = s.coordinator.flakes["decode"]._proto
+                families = set(s.telemetry.metrics())
+            seen[on] = (list(built), decode._stage, decode._tick_t,
+                        families)
+        # the stub is on the path: with telemetry on it builds spans
+        assert {"floe.dispatch", "floe.decode.launch"} <= set(seen[True][0])
+        assert "floe_decode_steps_total" in seen[True][3]
+        spans, stage, tick_t, families = seen[False]
+        assert spans == [] and stage is None and tick_t is None
+        assert not {f for f in families if f.startswith(
+            ("floe_decode_", "floe_prefill_"))}
+
     def test_paired_requests_share_steps(self):
         flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=4,
                                   seed=0)
@@ -262,10 +384,20 @@ class TestServingPlane:
             # deterministic weights: a cross-kill duplicate must agree
             assert len({tuple(r["tokens"]) for r in rs}) == 1
 
-    def test_hot_swap_zero_loss_version_tags(self):
+    def test_hot_swap_zero_loss_version_tags(self, monkeypatch):
         """Live weight hot-swap mid-stream: every request answered exactly
         once; completions before the swap tag version 0, after it version
         1; the in-flight generation crosses the swap intact."""
+        # pace the decode steps: unpaced, the carried generation's 11 steps
+        # take ~15 ms on a CPU and, on a loaded host, finished before the
+        # swap landed (the test then saw version 0)
+        real_step = kv.decode_step
+
+        def paced_step(*args, **kwargs):
+            time.sleep(0.02)
+            return real_step(*args, **kwargs)
+
+        monkeypatch.setattr(kv, "decode_step", paced_step)
         flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=3,
                                   seed=0, version=0)
         with flow.session() as s:

@@ -16,7 +16,7 @@ import time
 import numpy as np
 import pytest
 
-from conftest import wait_until
+from testkit import wait_until
 from repro import ClusterSpec
 from repro.api import Flow
 from repro.core import (ArrayBatch, Coordinator, FloeGraph, FnPellet,
@@ -391,6 +391,24 @@ def test_traces_span_every_hop():
             # hops are causally ordered
             assert all(a["t_start"] <= b["t_end"]
                        for a, b in zip(spans, spans[1:]))
+            assert all(sp["queue_wait"] >= 0.0 for sp in spans)
+    # behind a slow stage rows queue, and its spans report the wait the
+    # dispatch observed (the same value the queue-wait histogram takes)
+    slow = Flow("slow")
+    a = slow.pellet("a", lambda: FnPellet(lambda x: x, sequential=True))
+    b = slow.pellet("b", lambda: FnPellet(
+        lambda x: (time.sleep(0.003), x)[1], sequential=True)).batch(1)
+    a >> b
+    with slow.session(trace_sample=1.0) as s:
+        s.inject_many("a", list(range(20)))
+        s.results()
+        waits = [sp["queue_wait"] for tid in s.trace()
+                 for sp in s.trace(tid) if sp["stage"] == "b"]
+        hist = s.telemetry.queue_wait.labels(stage="b").snapshot()
+    assert len(waits) == 20 and hist["count"] == 20
+    # the last row waited behind the 19 before it
+    assert max(waits) >= 0.003 * 10
+    assert sum(waits) == pytest.approx(hist["sum"], rel=1e-6, abs=1e-6)
 
 
 def test_trace_sampling_fraction():

@@ -12,11 +12,12 @@ one process may load the TPU library, and a module that touched it while
 being collected would give pytest-xdist workers different test sets.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from conftest import load_chip_smoke
+from testkit import load_chip_smoke
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
@@ -101,6 +102,13 @@ def _decode_step(chip):
                                 slots, slots, spec=SPEC, interpret=False)
 
 
+#: the Pallas kernel each program calls, by the ``name=`` of its
+#: ``pallas_call``: the device trace names the kernel's operation so
+KERNEL_OF = {_flash: "flash_attention", _decode_attention: "decode_attention",
+             _cluster_distance: "cluster_distance",
+             _prefill: "flash_attention", _decode_step: "decode_attention"}
+
+
 @pytest.mark.timeout(240)
 @pytest.mark.parametrize("lower", [_flash, _decode_attention,
                                    _cluster_distance, _prefill,
@@ -108,7 +116,11 @@ def _decode_step(chip):
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_one_v5e_chip(one_chip, lower):
     compiled = lower(one_chip).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernel = KERNEL_OF[lower]
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call\(", text), \
+        f"no custom call named {kernel!r}"
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
