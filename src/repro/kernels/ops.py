@@ -73,18 +73,23 @@ def flash_attention_op(q, k, v, *, causal: bool = True,
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k",
                                              "interpret"))
-def decode_attention_op(q, k_cache, v_cache, lengths, *,
-                        window: Optional[int] = None, block_k: int = 128,
+def decode_attention_op(q, k_cache, v_cache, lengths, layer=0, *,
+                        window: Optional[int] = None,
+                        block_k: Optional[int] = None,
                         interpret: bool = False) -> jnp.ndarray:
-    """Padded flash-decode: q (B,H,hd), caches (B,S,Hkv,hd), lengths (B,)."""
+    """Padded flash-decode: q (B,H,hd); caches (L,B,S,Hkv,hd), read in
+    place at ``layer``, or one layer's (B,S,Hkv,hd); lengths (B,), each
+    >= 1.  ``block_k`` defaults to ``decode_attention.kv_block_k``."""
     B, H, hd = q.shape
+    if k_cache.ndim == 4:
+        k_cache, v_cache = k_cache[None], v_cache[None]
     scale = 1.0 / (hd ** 0.5)
     qp, _ = _pad_last(q)
     kp, _ = _pad_last(k_cache)
     vp, _ = _pad_last(v_cache)
-    out = _dec.decode_attention(qp, kp, vp, lengths, window=window,
-                                block_k=min(block_k, kp.shape[1]),
-                                sm_scale=scale, interpret=interpret)
+    out = _dec.decode_attention(qp, kp, vp, lengths, layer, window=window,
+                                block_k=block_k, sm_scale=scale,
+                                interpret=interpret)
     return out[:, :, :hd]
 
 

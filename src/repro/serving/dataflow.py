@@ -48,6 +48,7 @@ import numpy as np
 
 from ..api.builder import Flow
 from ..core.pellet import Drop, KeyedEmit, PushPellet
+from ..kernels.decode_attention import kv_block_k, kv_tiles_read
 from ..kernels.ops import pallas_interpret
 from ..telemetry.tracing import NO_SPAN, span
 from . import kv
@@ -178,7 +179,8 @@ class DecodePellet(_StagePellet):
     sequential = True
     __floe_state__ = ("k", "v", "lengths", "last_tok", "live", "meta",
                       "tick_pending", "n_steps", "n_spliced")
-    _steps = _tokens = _tick_wait = None      # counters, once bound
+    #: counters, once bound (the KV tile pair on the kernel path only)
+    _steps = _tokens = _tick_wait = _kv_read = _kv_tiles = None
     #: ``perf_counter`` when the pending tick was emitted; instance-only
     #: (not checkpointed), so the first tick after a restore or a swap is
     #: not observed
@@ -220,6 +222,15 @@ class DecodePellet(_StagePellet):
             "floe_decode_tick_wait_seconds",
             "Time from a decode tick's emission to the start of the step "
             "it drives, by stage.", ("stage",)).labels(stage=stage)
+        if not self.ref_path:
+            self._kv_read = _counter(
+                registry, "floe_decode_kv_tiles_read_total",
+                "KV cache tiles the flash-decode kernel fetched, summed "
+                "over layers and K/V, by stage.", stage)
+            self._kv_tiles = _counter(
+                registry, "floe_decode_kv_tiles_total",
+                "KV cache tiles the decode steps' caches held, summed over "
+                "layers and K/V, by stage.", stage)
 
     # -- checkpoint / hot-swap state -----------------------------------------
     def get_state(self) -> Dict[str, Any]:
@@ -323,6 +334,12 @@ class DecodePellet(_StagePellet):
         if self._steps is not None:
             self._steps.inc()
             self._tokens.inc(len(live))
+        if self._kv_read is not None:
+            L, S = self.spec.n_layers, self.spec.max_len
+            bk = kv_block_k(S, self.spec.n_kv_heads, self.spec.head_dim)
+            # the kernel attends the step's new position too
+            self._kv_read.inc(2 * L * kv_tiles_read(self.lengths + 1, bk))
+            self._kv_tiles.inc(2 * L * self.n_slots * -(-S // bk))
         with self._span("floe.decode.bookkeep"):
             for s in live:
                 s = int(s)

@@ -156,7 +156,9 @@ def _decode_impl(params: Dict[str, jnp.ndarray], k_cache: jnp.ndarray,
         vn = (h @ params["wv"][l]).reshape(B, Hkv, hd)
         k_cache = k_cache.at[l, rows, lengths].set(kn)
         v_cache = v_cache.at[l, rows, lengths].set(vn)
-        o = dec_attn(q, k_cache[l], v_cache[l], lengths + 1)
+        # the stacked caches and the layer, not ``k_cache[l]``: the kernel
+        # reads layer l where it lies, so XLA never copies a layer out
+        o = dec_attn(q, k_cache, v_cache, lengths + 1, l)
         x = x + o.reshape(B, H * hd) @ params["wo"][l]
         h2 = _rms(x, params["ln2"][l])
         x = x + jax.nn.silu(h2 @ params["w1"][l]) @ params["w2"][l]
@@ -178,8 +180,8 @@ def decode_step(params, k_cache, v_cache, lengths, tokens, *, spec: LMSpec,
     """
     return _decode_impl(
         params, k_cache, v_cache, lengths, tokens, spec,
-        lambda q, k, v, lens: kops.decode_attention_op(
-            q, k, v, lens, interpret=interpret))
+        lambda q, k, v, lens, l: kops.decode_attention_op(
+            q, k, v, lens, l, interpret=interpret))
 
 
 def decode_step_ref(params, k_cache, v_cache, lengths, tokens, *,
@@ -187,7 +189,8 @@ def decode_step_ref(params, k_cache, v_cache, lengths, tokens, *,
     """Ref twin through ``kernels.ref.decode_attention``."""
     return _decode_impl(
         params, k_cache, v_cache, lengths, tokens, spec,
-        lambda q, k, v, lens: kref.decode_attention(q, k, v, lens))
+        lambda q, k, v, lens, l: kref.decode_attention(q, k[l], v[l],
+                                                       lens))
 
 
 # -- slot splice ------------------------------------------------------------

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import decode_attention as dec
 from repro.kernels import ops, ref
 
 
@@ -67,6 +68,14 @@ def test_flash_attention_non_causal():
 # decode attention
 # ---------------------------------------------------------------------------
 
+def _nan_past(cache, lengths):
+    """``cache`` (B,S,Hkv,hd) with every position at or past its slot's
+    length set to NaN: a tile the kernel should skip, or a masked
+    position it should not use, then poisons the output."""
+    pos = jnp.arange(cache.shape[1])[None, :, None, None]
+    return jnp.where(pos < lengths[:, None, None, None], cache, jnp.nan)
+
+
 @pytest.mark.parametrize("B,S,H,Hkv,hd", [
     (2, 128, 4, 2, 64),
     (3, 96, 5, 5, 24),
@@ -79,22 +88,65 @@ def test_decode_attention_sweep(B, S, H, Hkv, hd, dtype):
     v = rand(2, (B, S, Hkv, hd), dtype)
     lengths = jnp.asarray([(7 * (i + 3)) % S + 1 for i in range(B)],
                           jnp.int32)
-    got = ops.decode_attention_op(q, k, v, lengths, block_k=32,
-                                  interpret=True)
+    got = ops.decode_attention_op(q, _nan_past(k, lengths),
+                                  _nan_past(v, lengths), lengths,
+                                  block_k=32, interpret=True)
     want = ref.decode_attention(q, k, v, lengths)
     assert maxerr(got, want) < TOL[dtype]
 
 
-def test_decode_attention_window():
+@pytest.mark.parametrize("window,lengths", [
+    (16, [100, 64]),
+    (16, [105, 97]),        # windows from tile 2 into tile 3
+    (48, [100, 40]),        # tiles 1-3; tile 0 only
+    (200, [128, 33]),       # wider than the cache
+])
+def test_decode_attention_window(window, lengths):
     B, S = 2, 128
     q = rand(0, (B, 4, 64), jnp.float32)
     k = rand(1, (B, S, 2, 64), jnp.float32)
     v = rand(2, (B, S, 2, 64), jnp.float32)
-    lengths = jnp.array([100, 64], jnp.int32)
-    got = ops.decode_attention_op(q, k, v, lengths, window=16, block_k=32,
-                                  interpret=True)
-    want = ref.decode_attention(q, k, v, lengths, window=16)
+    lengths = jnp.array(lengths, jnp.int32)
+    got = ops.decode_attention_op(q, _nan_past(k, lengths),
+                                  _nan_past(v, lengths), lengths,
+                                  window=window, block_k=32, interpret=True)
+    want = ref.decode_attention(q, k, v, lengths, window=window)
     assert maxerr(got, want) < TOL[jnp.float32]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("n", [1, 31, 32, 33, 128])     # 1, bk +- 1, S
+def test_decode_attention_stacked_tile_edges(n, layer):
+    """Stacked (L=3) caches read in place at ``layer``: each slot's length
+    on or beside a tile edge, NaN past every length and in every other
+    layer, against the reference on the clean layer."""
+    L, B, S, H, Hkv, hd = 3, 3, 128, 4, 2, 64
+    q = rand(0, (B, H, hd), jnp.float32)
+    k = rand(1, (B, S, Hkv, hd), jnp.float32)
+    v = rand(2, (B, S, Hkv, hd), jnp.float32)
+    lengths = jnp.array([n, S + 1 - n, 1 + n // 2], jnp.int32)
+    poison = jnp.full((L, B, S, Hkv, hd), jnp.nan, jnp.float32)
+    kc = poison.at[layer].set(_nan_past(k, lengths))
+    vc = poison.at[layer].set(_nan_past(v, lengths))
+    got = ops.decode_attention_op(q, kc, vc, lengths, layer, block_k=32,
+                                  interpret=True)
+    want = ref.decode_attention(q, k, v, lengths)
+    assert maxerr(got, want) < TOL[jnp.float32]
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_decode_attention_tile_span(window):
+    """The tiles the kernel reads: each slot's live ones, and all of them
+    when every slot is full."""
+    lengths = np.array([1, 32, 33, 100, 128])
+    first, last = dec.kv_tile_span(lengths, 32, window, np)
+    if window is None:
+        assert first == 0 and last.tolist() == [0, 0, 1, 3, 3]
+        assert dec.kv_tiles_read(lengths, 32) == 1 + 1 + 2 + 4 + 4
+        assert dec.kv_tiles_read(np.full(8, 512), 128) == 8 * 4
+    else:
+        assert first.tolist() == [0, 0, 0, 1, 2]
+        assert dec.kv_tiles_read(lengths, 32, window) == 1 + 1 + 2 + 3 + 2
 
 
 # ---------------------------------------------------------------------------

@@ -237,6 +237,11 @@ class TestServingPlane:
         # every request is prefilled once, in a batch padded to max_prompt
         assert value("floe_prefill_positions_total", "prefill") == \
             len(prompts) * max_prompt
+        # one tile holds the whole tiny cache: every slot, layer and K/V
+        # reads it, live or dead
+        tiles = decode.n_steps * 2 * SPEC.n_layers * decode.n_slots
+        assert value("floe_decode_kv_tiles_total", "decode") == tiles
+        assert value("floe_decode_kv_tiles_read_total", "decode") == tiles
 
     def test_profiler_spans_nest_in_dispatch(self, tmp_path):
         """Under the profiler, every decode step leaves one launch, one
@@ -313,11 +318,47 @@ class TestServingPlane:
                         families)
         # the stub is on the path: with telemetry on it builds spans
         assert {"floe.dispatch", "floe.decode.launch"} <= set(seen[True][0])
-        assert "floe_decode_steps_total" in seen[True][3]
+        assert {"floe_decode_steps_total", "floe_decode_kv_tiles_read_total",
+                "floe_decode_kv_tiles_total"} <= seen[True][3]
         spans, stage, tick_t, families = seen[False]
         assert spans == [] and stage is None and tick_t is None
         assert not {f for f in families if f.startswith(
             ("floe_decode_", "floe_prefill_"))}
+
+    @pytest.mark.parametrize("lengths,live,reads", [
+        # kernel lengths 1024, 1025, 2 -> 1 + 2 + 1 tiles of 1024, then
+        # 1025, 1026, 2 -> 2 + 2 + 1; x 2 layers x K/V
+        ([1023, 1024, 1], [True, True, False], [16, 20]),
+        # every slot dead but one at length 1: one tile each
+        ([1, 1, 1], [True, False, False], [12, 12]),
+    ])
+    def test_kv_tile_counters(self, lengths, live, reads):
+        """The decode step counts the KV tiles the flash-decode kernel
+        fetches (``kv_tiles_read`` of the lengths it gets) and the tiles
+        the caches hold."""
+        from types import SimpleNamespace
+        from repro.kernels.decode_attention import kv_block_k, kv_tiles_read
+        from repro.serving.dataflow import DecodePellet
+        from repro.telemetry import MetricsRegistry
+        spec = LMSpec(vocab=16, n_heads=2, n_kv_heads=1, head_dim=4,
+                      n_layers=2, max_len=2048)
+        assert kv_block_k(spec.max_len, spec.n_kv_heads,
+                          spec.head_dim) == 1024
+        pellet = DecodePellet(kv.init_params(spec, 0), spec, n_slots=3)
+        pellet.bind_telemetry(SimpleNamespace(registry=MetricsRegistry()),
+                              "decode")
+        for s, (n, on) in enumerate(zip(lengths, live)):
+            if on:
+                pellet._admit_row({"slot": s, "rid": s, "tok0": 1,
+                                   "length": n, "budget": 8, "t_sub": 0.0,
+                                   "t_first": 0.0}, [], spliced=True)
+        for want in reads:
+            before = (pellet._kv_read.value, pellet._kv_tiles.value)
+            assert want == 2 * spec.n_layers * kv_tiles_read(
+                pellet.lengths + 1, 1024)
+            pellet._step([])
+            assert pellet._kv_read.value - before[0] == want
+            assert pellet._kv_tiles.value - before[1] == 2 * 2 * 3 * 2
 
     def test_paired_requests_share_steps(self):
         flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=4,

@@ -72,12 +72,19 @@ def _flash(chip):
                                         interpret=False)
 
 
+def _cache(chip):
+    """The decode pellet's stacked ``(L, n_slots, max_len, Hkv, hd)``
+    cache."""
+    return _sds(chip, (SPEC.n_layers, SLOTS, SPEC.max_len,
+                       SPEC.n_kv_heads, SPEC.head_dim))
+
+
 def _decode_attention(chip):
     q = _sds(chip, (SLOTS, SPEC.n_heads, SPEC.head_dim))
-    cache = _sds(chip, (SLOTS, SPEC.max_len, SPEC.n_kv_heads,
-                        SPEC.head_dim))
+    cache = _cache(chip)
     lengths = _sds(chip, (SLOTS,), jnp.int32)
-    return ops.decode_attention_op.lower(q, cache, cache, lengths,
+    layer = _sds(chip, (), jnp.int32)
+    return ops.decode_attention_op.lower(q, cache, cache, lengths, layer,
                                          interpret=False)
 
 
@@ -95,8 +102,7 @@ def _prefill(chip):
 
 
 def _decode_step(chip):
-    cache = _sds(chip, (SPEC.n_layers, SLOTS, SPEC.max_len,
-                        SPEC.n_kv_heads, SPEC.head_dim))
+    cache = _cache(chip)
     slots = _sds(chip, (SLOTS,), jnp.int32)
     return kv.decode_step.lower(_param_shapes(chip, SPEC), cache, cache,
                                 slots, slots, spec=SPEC, interpret=False)
@@ -125,3 +131,33 @@ def test_compiles_for_one_v5e_chip(one_chip, lower):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < DEVICE_BYTES, f"{total} bytes do not fit one chip"
+
+
+def _results(text, shape):
+    """Instructions of compiled HLO ``text`` whose result is ``shape``
+    (layout aside), as ``(name, opcode)``."""
+    pat = rf"^\s*(?:ROOT )?%(\S+) = {re.escape(shape)}(?:\{{[^}}]*\}})? " \
+          rf"([\w-]+)\("
+    return re.findall(pat, text, re.M)
+
+
+@pytest.mark.timeout(240)
+def test_decode_step_reads_the_cache_in_place(one_chip):
+    """The flash-decode kernel reads the stacked caches where they lie:
+    no per-layer relayout of one layer's cache to ``(B, Hkv, S, hd)``,
+    and no whole-cache copy beyond the one per cache of the non-donated
+    input (ROADMAP S5); nor does its q operand cost a cast of ``wq``."""
+    text = _decode_step(one_chip).compile().as_text()
+    relayout = f"f32[{SLOTS},{SPEC.n_kv_heads},{SPEC.max_len}," \
+               f"{SPEC.head_dim}]"
+    assert _results(text, relayout) == []
+    whole = f"f32[{SPEC.n_layers},{SLOTS},{SPEC.max_len}," \
+            f"{SPEC.n_kv_heads},{SPEC.head_dim}]"
+    copies = [name for name, op in _results(text, whole) if op == "copy"]
+    assert len(copies) <= 2, copies
+    assert _results(text, whole), "the pattern matched no cache at all"
+    # the q projection stays one fusion on the f32 weight: handed queries
+    # as (B, H, hd), XLA writes a bf16 copy of every layer's wq first
+    entry = text[text.index("\nENTRY "):]
+    wq_bf16 = f"bf16[{SPEC.d_model},{SPEC.n_heads * SPEC.head_dim}]"
+    assert _results(entry, wq_bf16) == []
