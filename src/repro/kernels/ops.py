@@ -11,10 +11,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 
 from . import cluster_distance as _cd
 from . import decode_attention as _dec
 from . import flash_attention as _fa
+from . import moe_decode as _moe_dec
 from . import moe_dispatch as _moe
 from . import ssm_scan as _ssm
 
@@ -91,6 +93,63 @@ def decode_attention_op(q, k_cache, v_cache, lengths, layer=0, *,
                                 block_k=block_k, sm_scale=scale,
                                 interpret=interpret)
     return out[:, :, :hd]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_decode_op(x, expert_ids, weights, live, w_gate, w_up, w_down,
+                  layer=0, *, interpret: bool = False):
+    """Dropless decode experts: x (B, D); the top-k ``expert_ids`` and
+    renormalised ``weights`` (B, k); ``live`` (B,) bool; stacked experts
+    w_gate/w_up (L, E, D, F), w_down (L, E, F, D), read in place at
+    ``layer`` -> (y (B, D), experts fetched ())."""
+    return _moe_dec.moe_decode(x, expert_ids, weights, live, w_gate, w_up,
+                               w_down, layer, interpret=interpret)
+
+
+def _gmm_block(dim: int, most: int) -> int:
+    """The widest multiple of 128 up to ``most`` that divides ``dim``,
+    else the whole of ``dim``."""
+    for b in range(most, LANE - 1, -LANE):
+        if dim % b == 0:
+            return b
+    return dim
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def moe_grouped_op(x, expert_ids, weights, w_gate, w_up, w_down, layer=0,
+                   *, interpret: bool = False):
+    """Dropless experts over many tokens (prefill): x (T, D); top-k
+    ``expert_ids``/``weights`` (T, k); stacked experts as in
+    ``moe_decode_op``, at ``layer``.
+
+    The T*k picks are sorted by expert and run as grouped matmuls (the
+    megablox ``gmm`` Pallas kernel) over the layer's experts, addressed in
+    the stacked ``(L*E, ...)`` arrays through zero-sized groups for the
+    other layers, so no layer's experts are sliced out.  Every pick is
+    computed; each token's rows are gathered back and summed with its
+    weights."""
+    T, D = x.shape
+    k = expert_ids.shape[1]
+    L, E, _, F = w_gate.shape
+    flat = expert_ids.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    rows = T * k
+    tm = 256 if rows >= 256 else rows + (-rows) % SUBLANE
+    xs = x[order // k]                                    # (T*k, D) sorted
+    xs = jnp.pad(xs, ((0, (-rows) % tm), (0, 0)))
+    sizes = jnp.zeros((L, E), jnp.int32).at[layer].set(
+        jnp.bincount(flat, length=E).astype(jnp.int32)).reshape(L * E)
+    up_tiles = (tm, _gmm_block(D, 768), _gmm_block(F, 896))
+    down_tiles = (tm, _gmm_block(F, 896), _gmm_block(D, 768))
+    g = _gmm(xs, w_gate.reshape(L * E, D, F), sizes, tiling=up_tiles,
+            interpret=interpret)
+    u = _gmm(xs, w_up.reshape(L * E, D, F), sizes, tiling=up_tiles,
+            interpret=interpret)
+    y = _gmm(jax.nn.silu(g) * u, w_down.reshape(L * E, F, D), sizes,
+            tiling=down_tiles, interpret=interpret)
+    # rows past the picks were never written: only sorted rows are read
+    back = y[jnp.argsort(order)].reshape(T, k, D)
+    return jnp.sum(back * weights[..., None].astype(jnp.float32), axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))

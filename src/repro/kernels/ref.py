@@ -96,6 +96,29 @@ def ssm_scan(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# dropless routed experts (serving: moe_decode, moe_grouped)
+# ---------------------------------------------------------------------------
+
+def moe_ffn(x: jnp.ndarray, expert_ids: jnp.ndarray, weights: jnp.ndarray,
+            w_gate: jnp.ndarray, w_up: jnp.ndarray,
+            w_down: jnp.ndarray) -> jnp.ndarray:
+    """Every expert on every row, combined by the top-k weights.
+
+    x (T,D); expert_ids/weights (T,k); w_gate/w_up (E,D,F); w_down (E,F,D)
+    -> (T,D) f32: ``sum_j weights[t,j] * FFN_{expert_ids[t,j]}(x[t])``
+    with ``FFN_e(h) = (silu(h Wg_e) * (h Wu_e)) Wd_e``."""
+    T = x.shape[0]
+    E = w_gate.shape[0]
+    comb = jnp.zeros((T, E), jnp.float32).at[
+        jnp.arange(T)[:, None], expert_ids].add(weights.astype(jnp.float32))
+    xf = x.astype(jnp.float32)
+    g = jnp.einsum("td,edf->tef", xf, w_gate.astype(jnp.float32))
+    u = jnp.einsum("td,edf->tef", xf, w_up.astype(jnp.float32))
+    a = jax.nn.silu(g) * u * comb[..., None]
+    return jnp.einsum("tef,efd->td", a, w_down.astype(jnp.float32))
+
+
+# ---------------------------------------------------------------------------
 # MoE dispatch / combine (dynamic port mapping)
 # ---------------------------------------------------------------------------
 

@@ -29,8 +29,9 @@ live slots.  At most one tick is in flight (``tick_pending``).
 Bound to a running stage (the engine calls ``bind_telemetry``), prefill
 and decode count their work in the coordinator's ``MetricsRegistry``
 (``floe_prefill_tokens_total``/``floe_prefill_positions_total``,
-``floe_decode_steps_total``/``floe_decode_tokens_total``, and the
-``floe_decode_tick_wait_seconds`` histogram) and open ``floe.prefill.*`` /
+``floe_decode_steps_total``/``floe_decode_tokens_total``, the
+``floe_decode_tick_wait_seconds`` histogram, and for an expert model
+``floe_moe_expert_fetches_total``) and open ``floe.prefill.*`` /
 ``floe.decode.*`` profiler spans around their device launches, host waits
 and bookkeeping.  Unbound (telemetry off), both are no-ops.
 
@@ -40,9 +41,11 @@ clients), plus ``t_sub``/``t_first``/``t_done`` for TTFT/TPOT accounting.
 """
 from __future__ import annotations
 
+import collections
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -179,8 +182,9 @@ class DecodePellet(_StagePellet):
     sequential = True
     __floe_state__ = ("k", "v", "lengths", "last_tok", "live", "meta",
                       "tick_pending", "n_steps", "n_spliced")
-    #: counters, once bound (the KV tile pair on the kernel path only)
-    _steps = _tokens = _tick_wait = _kv_read = _kv_tiles = None
+    #: counters, once bound (the KV tile pair on the kernel path only,
+    #: expert fetches for an expert spec only)
+    _steps = _tokens = _tick_wait = _kv_read = _kv_tiles = _fetches = None
     #: ``perf_counter`` when the pending tick was emitted; instance-only
     #: (not checkpointed), so the first tick after a restore or a swap is
     #: not observed
@@ -207,6 +211,8 @@ class DecodePellet(_StagePellet):
         self.tick_pending = False
         self.n_steps = 0
         self.n_spliced = 0
+        #: layers by attention window: the KV tiles a step reads
+        self._windows = collections.Counter(spec.window(l) for l in range(L))
 
     def bind_telemetry(self, telemetry: Any, stage: str) -> None:
         super().bind_telemetry(telemetry, stage)
@@ -231,6 +237,11 @@ class DecodePellet(_StagePellet):
                 registry, "floe_decode_kv_tiles_total",
                 "KV cache tiles the decode steps' caches held, summed over "
                 "layers and K/V, by stage.", stage)
+        if self.spec.n_experts:
+            self._fetches = _counter(
+                registry, "floe_moe_expert_fetches_total",
+                "Distinct (layer, expert) pairs the live slots of each "
+                "decode step picked, by stage.", stage)
 
     # -- checkpoint / hot-swap state -----------------------------------------
     def get_state(self) -> Dict[str, Any]:
@@ -323,22 +334,32 @@ class DecodePellet(_StagePellet):
             self._tick_t = None
         step = kv.decode_step_ref if self.ref_path else kv.decode_step
         kwargs = {} if self.ref_path else {"interpret": self.interpret}
+        # an expert step also takes the live mask (dead slots pick no
+        # expert) and returns each layer's count of experts fetched
+        live_arg = (jnp.asarray(self.live),) if self.spec.n_experts else ()
         with self._span("floe.decode.launch"):
-            logits, self.k, self.v = step(
+            logits, self.k, self.v, *fetched = step(
                 self.params, self.k, self.v, jnp.asarray(self.lengths),
-                jnp.asarray(self.last_tok), spec=self.spec, **kwargs)
+                jnp.asarray(self.last_tok), *live_arg, spec=self.spec,
+                **kwargs)
         with self._span("floe.decode.sync"):
-            nxt = _np32(kv.greedy(logits))
+            nxt, *fetched = jax.device_get((kv.greedy(logits), *fetched))
+            nxt = _np32(nxt)
         self.n_steps += 1
         live = np.nonzero(self.live)[0]
         if self._steps is not None:
             self._steps.inc()
             self._tokens.inc(len(live))
+        if self._fetches is not None:
+            self._fetches.inc(int(np.sum(fetched[0])))
         if self._kv_read is not None:
             L, S = self.spec.n_layers, self.spec.max_len
             bk = kv_block_k(S, self.spec.n_kv_heads, self.spec.head_dim)
-            # the kernel attends the step's new position too
-            self._kv_read.inc(2 * L * kv_tiles_read(self.lengths + 1, bk))
+            # the kernel attends the step's new position too, and a
+            # windowed layer only the tiles its window reaches
+            self._kv_read.inc(2 * sum(
+                n * kv_tiles_read(self.lengths + 1, bk, w)
+                for w, n in self._windows.items()))
             self._kv_tiles.inc(2 * L * self.n_slots * -(-S // bk))
         with self._span("floe.decode.bookkeep"):
             for s in live:

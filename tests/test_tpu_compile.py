@@ -28,6 +28,13 @@ from repro.serving import kv
 DEVICE_BYTES = 16e9
 SPEC = load_chip_smoke().QWEN3_1_7B
 SLOTS, PROMPT = 8, 128
+#: the benchmark's expert model: one 4-layer stage of Mellum2-12B-A2.5B at
+#: its published widths, prompts to 4,096 positions
+MOE = kv.LMSpec(vocab=98304, n_heads=32, n_kv_heads=4, head_dim=128,
+                n_layers=4, max_len=4224, d_model=2304,
+                windows=(1024, 1024, 1024, None), n_experts=64, top_k=8,
+                expert_width=896)
+MOE_PROMPT = 4096
 
 
 @pytest.fixture(scope="module")
@@ -55,14 +62,7 @@ def _sds(chip, shape, dtype=jnp.float32):
 
 def _param_shapes(chip, spec):
     """``kv.init_params``'s layout as shapes only (no host arrays)."""
-    V, D, L = spec.vocab, spec.d_model, spec.n_layers
-    Q, KV = spec.n_heads * spec.head_dim, spec.n_kv_heads * spec.head_dim
-    F = spec.ffn_mult * D
-    shapes = {"embed": (V, D), "head": (V, D), "wq": (L, D, Q),
-              "wk": (L, D, KV), "wv": (L, D, KV), "wo": (L, Q, D),
-              "w1": (L, D, F), "w2": (L, F, D), "ln1": (L, D),
-              "ln2": (L, D), "ln_f": (D,)}
-    return {k: _sds(chip, s) for k, s in shapes.items()}
+    return {k: _sds(chip, s) for k, s in kv.param_shapes(spec).items()}
 
 
 def _flash(chip):
@@ -108,17 +108,46 @@ def _decode_step(chip):
                                 slots, slots, spec=SPEC, interpret=False)
 
 
+def _moe_decode(chip):
+    L, E, D, F = MOE.n_layers, MOE.n_experts, MOE.d_model, MOE.expert_width
+    return ops.moe_decode_op.lower(
+        _sds(chip, (SLOTS, D)), _sds(chip, (SLOTS, MOE.top_k), jnp.int32),
+        _sds(chip, (SLOTS, MOE.top_k)), _sds(chip, (SLOTS,), jnp.bool_),
+        _sds(chip, (L, E, D, F)), _sds(chip, (L, E, D, F)),
+        _sds(chip, (L, E, F, D)), _sds(chip, (), jnp.int32),
+        interpret=False)
+
+
+def _moe_prefill(chip):
+    return kv.prefill.lower(_param_shapes(chip, MOE),
+                            _sds(chip, (SLOTS, MOE_PROMPT), jnp.int32),
+                            _sds(chip, (SLOTS,), jnp.int32),
+                            spec=MOE, interpret=False)
+
+
+def _moe_decode_step(chip):
+    cache = _sds(chip, (MOE.n_layers, SLOTS, MOE.max_len, MOE.n_kv_heads,
+                        MOE.head_dim))
+    slots = _sds(chip, (SLOTS,), jnp.int32)
+    return kv.decode_step.lower(_param_shapes(chip, MOE), cache, cache,
+                                slots, slots, _sds(chip, (SLOTS,), jnp.bool_),
+                                spec=MOE, interpret=False)
+
+
 #: the Pallas kernel each program calls, by the ``name=`` of its
 #: ``pallas_call``: the device trace names the kernel's operation so
 KERNEL_OF = {_flash: "flash_attention", _decode_attention: "decode_attention",
              _cluster_distance: "cluster_distance",
-             _prefill: "flash_attention", _decode_step: "decode_attention"}
+             _prefill: "flash_attention", _decode_step: "decode_attention",
+             _moe_decode: "moe_decode", _moe_prefill: "flash_attention",
+             _moe_decode_step: "moe_decode"}
 
 
 @pytest.mark.timeout(240)
 @pytest.mark.parametrize("lower", [_flash, _decode_attention,
                                    _cluster_distance, _prefill,
-                                   _decode_step],
+                                   _decode_step, _moe_decode, _moe_prefill,
+                                   _moe_decode_step],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_one_v5e_chip(one_chip, lower):
     compiled = lower(one_chip).compile()
@@ -161,3 +190,16 @@ def test_decode_step_reads_the_cache_in_place(one_chip):
     entry = text[text.index("\nENTRY "):]
     wq_bf16 = f"bf16[{SPEC.d_model},{SPEC.n_heads * SPEC.head_dim}]"
     assert _results(entry, wq_bf16) == []
+
+
+@pytest.mark.timeout(240)
+def test_expert_decode_step_reads_experts_in_place(one_chip):
+    """The decode expert kernel reads the stacked ``(L, E, D, F)`` experts
+    where they lie: no layer's experts are sliced or copied out, and each
+    layer calls the kernel once."""
+    text = _moe_decode_step(one_chip).compile().as_text()
+    E, D, F = MOE.n_experts, MOE.d_model, MOE.expert_width
+    for shape in (f"f32[{E},{D},{F}]", f"f32[{E},{F},{D}]"):
+        assert _results(text, shape) == [], shape
+    calls = re.findall(r"%moe_decode(?:\.\d+)? = [^\n]*custom-call\(", text)
+    assert len(calls) == MOE.n_layers
