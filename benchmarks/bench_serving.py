@@ -63,8 +63,10 @@ def _warm_jit(spec, n_slots: int, max_prompt: int = 8) -> None:
         kv.prefill(params, jnp.zeros((b, max_prompt), jnp.int32),
                    jnp.ones((b,), jnp.int32), spec=spec,
                    interpret=interpret)
-    zeros = jnp.zeros((L, n_slots, spec.max_len, Hkv, hd), jnp.float32)
-    kv.decode_step(params, zeros, zeros,
+    shape = (L, n_slots, spec.max_len, Hkv, hd)
+    # two buffers: the step consumes (is donated) each cache it is handed
+    kv.decode_step(params, jnp.zeros(shape, jnp.float32),
+                   jnp.zeros(shape, jnp.float32),
                    jnp.ones((n_slots,), jnp.int32),
                    jnp.zeros((n_slots,), jnp.int32), spec=spec,
                    interpret=interpret)

@@ -152,10 +152,12 @@ def _kernel_vs_ref(params, spec: LMSpec, prompts, *, max_prompt: int,
     prefill_err, prefill_agree = compare(logits, ref_logits)
     del ref_logits
     tok0 = kv.greedy(logits)
-    step, _, _ = kv.decode_step(params, kc, vc, lengths, tok0, spec=spec,
-                                interpret=interpret)
+    # the reference first: the kernel's step consumes the caches it is
+    # handed (donated)
     ref_step, _, _ = kv.decode_step_ref(params, kc, vc, lengths, tok0,
                                         spec=spec)
+    step, _, _ = kv.decode_step(params, kc, vc, lengths, tok0, spec=spec,
+                                interpret=interpret)
     decode_err, decode_agree = compare(step, ref_step)
     return {"prefill_err": prefill_err, "prefill_agree": prefill_agree,
             "decode_err": decode_err, "decode_agree": decode_agree}

@@ -136,10 +136,13 @@ class AdjustableSemaphore:
         self._cond = threading.Condition()
 
     def acquire(self, timeout: Optional[float] = None) -> bool:
+        """Take a slot; False on timeout, and at once while the capacity
+        is 0: a pool scaled to no cores has no slot to wait for."""
         with self._cond:
-            ok = self._cond.wait_for(lambda: self._in_use < self._capacity,
-                                     timeout=timeout)
-            if not ok:
+            ok = self._cond.wait_for(
+                lambda: self._in_use < self._capacity or self._capacity == 0,
+                timeout=timeout)
+            if not ok or self._capacity == 0:
                 return False
             self._in_use += 1
             return True
@@ -462,6 +465,7 @@ class Flake:
         self._thread: Optional[threading.Thread] = None
         self._window_buf: List[Any] = []
         self._inflight = 0             # guarded-by: _inflight_cond
+        self._popping = False          # guarded-by: _inflight_cond
         self._inflight_cond = threading.Condition()
         self._done_seqs: set = set()           # speculative dedup
         self.speculative_timeout = speculative_timeout
@@ -761,15 +765,16 @@ class Flake:
 
     # -- dispatch ---------------------------------------------------------------
     def _dispatch_loop(self) -> None:
-        proto = self._proto
         while not self._stop.is_set():
             self.heartbeat = time.time()
             if self._paused.is_set() or self._drain.is_set() or self.cores == 0:
                 with self._wake:
                     self._wake.wait(timeout=0.05)
                 continue
-            work = self._collect()
-            if work is None:
+            work = self._pop()
+            if work is not None:
+                self._dispatch(work)
+            else:
                 with self._wake:
                     hold = self._batch_deadline
                     remaining = (hold - time.time()) if hold is not None \
@@ -783,20 +788,52 @@ class Flake:
                     elif (self.queue_length() == 0 and not self._stop.is_set()
                             and not self._ready()):
                         self._wake.wait(timeout=0.05)
-                continue
-            kind, item, credits, wait = work
-            with self._pellet_lock:
-                proto = self._proto
+
+    def _pop(self):
+        """``_collect`` in sight of a drain: a drain that finds this flake
+        quiescent must not miss a message popped from its channel but not
+        yet run (a cut would then hold neither the message nor its
+        effect).  ``_popping`` holds the drain's wait from before the
+        drain check until popped work is counted in flight; the work
+        releases that count when it is done (``_dispatch``)."""
+        with self._inflight_cond:
+            self._popping = True
+        work = None
+        try:
+            if not self._drain.is_set():
+                work = self._collect()
+        finally:
+            with self._inflight_cond:
+                if work is not None:
+                    self._inflight += 1
+                self._popping = False
+                self._inflight_cond.notify_all()
+        return work
+
+    def _dispatch(self, work) -> None:
+        """Run or submit one unit of ``_pop``'s work and release its
+        in-flight count when it is done: a pooled instance takes the count
+        over, anything else runs here in the dispatch thread, visible to
+        ``_wait_quiescent`` (a sequential/pull pellet mid-compute must not
+        look quiescent to a concurrent sync update or recompose commit)."""
+        kind, item, credits, wait = work
+        with self._pellet_lock:
+            proto = self._proto
+        if (kind != "landmark" and not proto.sequential
+                and not isinstance(proto, PullPellet)
+                and self._submit(kind, item, credits, wait)):
+            return
+        try:
             if kind == "landmark":
                 # a landmark must not overtake data: wait for in-flight
                 # data-parallel instances to complete and deliver outputs
                 # before forwarding the flush marker downstream
-                self._wait_quiescent()
+                self._wait_quiescent(own=1)
                 self._finish(item, credits, forward=True)
-            elif proto.sequential or isinstance(proto, PullPellet):
-                self._run_inline(kind, item, credits, wait)
             else:
-                self._submit(kind, item, credits, wait)
+                self._run_task(kind, item, credits, wait)
+        finally:
+            self._inflight_dec_local()
 
     def _observe_wait(self, head_ts: float, rows: int) -> float:
         """Queue-wait histogram: time from enqueue to dispatch, observed
@@ -975,27 +1012,13 @@ class Flake:
         return bmax
 
     # -- execution ---------------------------------------------------------------
-    def _run_inline(self, kind: str, item, credits: int,
-                    wait: float = 0.0) -> None:
-        """Run in the dispatch thread, visible to ``_wait_quiescent``.
-
-        Without the local in-flight accounting, a sequential/pull pellet
-        mid-compute would look quiescent to a concurrent sync update or
-        recompose commit.
-        """
-        self._inflight_inc_local()
-        try:
-            self._run_task(kind, item, credits, wait)
-        finally:
-            self._inflight_dec_local()
-
     def _submit(self, kind: str, item, credits: int,
-                wait: float = 0.0) -> None:
+                wait: float = 0.0) -> bool:
+        """Hand work and its in-flight count to a pooled instance; False
+        when no instance slot frees within 30 s, or at once when cores are
+        0 (a drain waits on this work): the caller then runs it inline."""
         if not self._sem.acquire(timeout=30):
-            # no instance slot (cores may be 0) — run inline as fallback
-            self._run_inline(kind, item, credits, wait)
-            return
-        self._inflight_inc_local()
+            return False
         fut = self._pool.submit(self._run_pooled, kind, item, credits,
                                 wait=wait)
         if self.speculative_timeout is not None and kind == "msg":
@@ -1004,6 +1027,7 @@ class Flake:
                     (time.time() + self.speculative_timeout,
                      fut, item, credits))
                 self._spec_cond.notify_all()
+        return True
 
     def _spec_loop(self) -> None:
         """Shared straggler watchdog: ONE thread arms every backup task.
@@ -1656,11 +1680,13 @@ class Flake:
             self._inflight -= 1
             self._inflight_cond.notify_all()
 
-    def _wait_quiescent(self, timeout: float = 30.0) -> bool:
+    def _wait_quiescent(self, timeout: float = 30.0, own: int = 0) -> bool:
+        """Wait until no work but the caller's ``own`` counts is in
+        flight, and no pop is under way."""
         deadline = time.time() + timeout
         with self._inflight_cond:
             return self._inflight_cond.wait_for(
-                lambda: self._inflight == 0,
+                lambda: self._inflight <= own and not self._popping,
                 timeout=max(0.0, deadline - time.time()))
 
 

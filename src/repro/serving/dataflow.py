@@ -246,7 +246,10 @@ class DecodePellet(_StagePellet):
     # -- checkpoint / hot-swap state -----------------------------------------
     def get_state(self) -> Dict[str, Any]:
         # host-materialized + deep-copied: the snapshot must not alias
-        # arrays/lists the running pellet keeps mutating after the cut
+        # arrays/lists the running pellet keeps mutating after the cut.
+        # The step and the splice donate the caches they are handed, so
+        # this reads them only between steps: the engine calls it from a
+        # frozen cut or a quiesced flake
         return {"k": np.asarray(self.k), "v": np.asarray(self.v),
                 "lengths": self.lengths.copy(),
                 "last_tok": self.last_tok.copy(), "live": self.live.copy(),
@@ -277,12 +280,9 @@ class DecodePellet(_StagePellet):
             return NotImplemented
         rows = int(np.asarray(cols["slot"]).shape[0])
         emits: List[List[Any]] = [[] for _ in range(rows)]
-        slots = np.asarray(cols["slot"], dtype=np.int64)
         with self._span("floe.decode.splice", rows=rows):
-            self.k = self.k.at[:, slots].set(
-                jnp.moveaxis(jnp.asarray(cols["k"]), 0, 1))
-            self.v = self.v.at[:, slots].set(
-                jnp.moveaxis(jnp.asarray(cols["v"]), 0, 1))
+            self.k, self.v = kv.splice(self.k, self.v, _np32(cols["slot"]),
+                                       cols["k"], cols["v"])
             for i in range(rows):
                 self._admit_row({name: col[i] for name, col in cols.items()},
                                 emits[i], spliced=True)
@@ -297,9 +297,10 @@ class DecodePellet(_StagePellet):
             self._maybe_tick(emits)
         elif isinstance(payload, dict) and "slot" in payload:
             # degraded single-row splice (row-wise fallback path)
-            s = int(payload["slot"])
-            self.k = kv.splice(self.k, payload["k"], s)
-            self.v = kv.splice(self.v, payload["v"], s)
+            self.k, self.v = kv.splice(
+                self.k, self.v, _np32([payload["slot"]]),
+                jnp.asarray(payload["k"])[None],
+                jnp.asarray(payload["v"])[None])
             self._admit_row(payload, emits, spliced=True)
             self._maybe_tick(emits)
         return emits or Drop

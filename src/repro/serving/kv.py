@@ -249,7 +249,8 @@ def _decode_impl(params: Dict[str, jnp.ndarray], k_cache: jnp.ndarray,
     return out + (jnp.stack(fetched),) if fetched else out
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "interpret"))
+@functools.partial(jax.jit, static_argnames=("spec", "interpret"),
+                   donate_argnames=("k_cache", "v_cache"))
 def decode_step(params, k_cache, v_cache, lengths, tokens, live=None, *,
                 spec: LMSpec, interpret: bool):
     """One continuous-batching decode step over every slot, driven by the
@@ -265,6 +266,10 @@ def decode_step(params, k_cache, v_cache, lengths, tokens, live=None, *,
     Returns ``(logits, k_cache, v_cache)``.  An expert spec also takes
     ``live (B,)`` bool, so that dead slots pick no expert, and returns
     each layer's count of experts fetched, ``(L,)`` int32, last.
+
+    The caches are donated: each step writes its new rows into the
+    buffers it is handed, and the arrays passed in are deleted.  Rebind
+    them to the returned caches; never pass one buffer as both.
     """
     return _decode_impl(
         params, k_cache, v_cache, lengths, tokens, live, spec,
@@ -292,11 +297,16 @@ def decode_step_ref(params, k_cache, v_cache, lengths, tokens, live=None,
 
 # -- slot splice ------------------------------------------------------------
 
-def splice(cache: jnp.ndarray, row: Any, slot: Any) -> jnp.ndarray:
-    """Write one request's prefill cache ``row (L, Smax, Hkv, hd)`` into
-    decode-slot ``slot`` of ``cache (L, n_slots, Smax, Hkv, hd)`` — the
-    continuous-batching splice (admit → **splice** → free)."""
-    return cache.at[:, int(slot)].set(jnp.asarray(row))
+@functools.partial(jax.jit, donate_argnames=("k_cache", "v_cache"))
+def splice(k_cache, v_cache, slots, k_rows, v_rows):
+    """Write requests' prefill cache rows ``(B, L, Smax, Hkv, hd)`` into
+    decode slots ``slots (B,)`` of the stacked ``(L, n_slots, Smax, Hkv,
+    hd)`` caches — the continuous-batching splice (admit → **splice** →
+    free).  Donated like ``decode_step``'s: the rows land in the buffers
+    handed in, and one program is compiled per row count ``B``.  Returns
+    ``(k_cache, v_cache)``."""
+    return (k_cache.at[:, slots].set(jnp.moveaxis(k_rows, 0, 1)),
+            v_cache.at[:, slots].set(jnp.moveaxis(v_rows, 0, 1)))
 
 
 def greedy(logits: Any) -> jnp.ndarray:

@@ -15,6 +15,7 @@ from repro.data import TokenPipeline
 from repro.launch.steps import make_train_step
 from repro.models import Model
 from repro.optim import init_state
+from testkit import wait_until
 
 
 @pytest.fixture(scope="module")
@@ -170,6 +171,79 @@ def test_checkpoint_captures_push_pellet_instance_state(tmp_path):
         assert proto.seen == {1, 2, 3}
         s2.inject_many("d", [3, 4])
         assert s2.results() == [4]          # 3 still deduped post-restore
+
+
+def test_frozen_cut_waits_for_a_popped_message(monkeypatch):
+    """A consistent cut holds each message once: in a channel, or in the
+    state of the pellet that ran it.  The dispatcher here lingers after
+    each pop, so a drain that counted only running tasks would cut between
+    the pop and the run and lose the popped messages."""
+    from repro.api import Flow
+    from repro.core import PushPellet
+    from repro.core.engine import Flake
+
+    class Count(PushPellet):
+        __floe_state__ = ("n",)
+        sequential = True
+
+        def __init__(self):
+            self.n = 0
+
+        def compute(self, x):
+            self.n += 1
+            return x
+
+    collect = Flake._collect
+
+    def lingering(flake):
+        work = collect(flake)
+        if work is not None and flake.name == "count":
+            time.sleep(0.02)
+        return work
+
+    monkeypatch.setattr(Flake, "_collect", lingering)
+    flow = Flow("cut")
+    flow.pellet("count", Count).batch(1)     # one message a pop
+    with flow.session() as s:
+        s.inject_many("count", list(range(30)))
+        flake = s.coordinator.flakes["count"]
+        cuts = []
+        for _ in range(5):
+            time.sleep(0.03)
+            with s.coordinator.frozen():
+                cuts.append(flake._proto.n + sum(
+                    len(c) for c in flake.inputs.values()))
+        assert sorted(s.results(timeout=30)) == list(range(30))
+    assert cuts == [30] * 5
+
+
+def test_frozen_cut_of_a_flake_scaled_to_no_cores():
+    """A dispatcher waiting for an instance slot holds a popped message
+    that the cut must include.  Scaled to no cores, the flake has no slot
+    to wait for: the message runs inline at once, and the cut does not
+    wait out the slot's timeout."""
+    from repro.api import Flow
+    from repro.core import FnPellet
+
+    def slow(x):
+        time.sleep(0.2)
+        return x
+
+    flow = Flow("scaled")
+    flow.pellet("work", lambda: FnPellet(slow), cores=1).batch(1)
+    with flow.session() as s:
+        s.inject_many("work", list(range(12)))
+        flake = s.coordinator.flakes["work"]
+        # every slot busy: the dispatcher waits on the next popped message
+        assert wait_until(lambda: flake._sem.free == 0, timeout=10)
+        t0 = time.time()
+        s.coordinator.set_cores("work", 0)
+        with s.coordinator.frozen(timeout=10):
+            held = len(flake.inputs["in"])
+        assert time.time() - t0 < 5
+        s.coordinator.set_cores("work", 1)
+        out = s.results(timeout=30)
+    assert sorted(out) == list(range(12)) and held < 12
 
 
 def test_checkpoint_custom_get_state_override(tmp_path):

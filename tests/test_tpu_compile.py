@@ -72,11 +72,13 @@ def _flash(chip):
                                         interpret=False)
 
 
-def _cache(chip):
+def _cache(chip, spec=SPEC):
     """The decode pellet's stacked ``(L, n_slots, max_len, Hkv, hd)``
-    cache."""
-    return _sds(chip, (SPEC.n_layers, SLOTS, SPEC.max_len,
-                       SPEC.n_kv_heads, SPEC.head_dim))
+    cache.  The lowerings pass this one shape for both caches: ``.lower()``
+    takes shapes, not buffers, so donating it twice is no fault here, as
+    it would be for one array passed as both."""
+    return _sds(chip, (spec.n_layers, SLOTS, spec.max_len,
+                       spec.n_kv_heads, spec.head_dim))
 
 
 def _decode_attention(chip):
@@ -126,12 +128,20 @@ def _moe_prefill(chip):
 
 
 def _moe_decode_step(chip):
-    cache = _sds(chip, (MOE.n_layers, SLOTS, MOE.max_len, MOE.n_kv_heads,
-                        MOE.head_dim))
+    cache = _cache(chip, MOE)
     slots = _sds(chip, (SLOTS,), jnp.int32)
     return kv.decode_step.lower(_param_shapes(chip, MOE), cache, cache,
                                 slots, slots, _sds(chip, (SLOTS,), jnp.bool_),
                                 spec=MOE, interpret=False)
+
+
+def _splice(chip, rows=3):
+    """The decode pellet's splice of a carrier of ``rows`` prefill rows."""
+    cache = _cache(chip)
+    carrier = _sds(chip, (rows, SPEC.n_layers, SPEC.max_len,
+                          SPEC.n_kv_heads, SPEC.head_dim))
+    return kv.splice.lower(cache, cache, _sds(chip, (rows,), jnp.int32),
+                           carrier, carrier)
 
 
 #: the Pallas kernel each program calls, by the ``name=`` of its
@@ -170,26 +180,44 @@ def _results(text, shape):
     return re.findall(pat, text, re.M)
 
 
+def _whole(spec):
+    """The stacked cache's result shape in compiled HLO text."""
+    return f"f32[{spec.n_layers},{SLOTS},{spec.max_len}," \
+           f"{spec.n_kv_heads},{spec.head_dim}]"
+
+
 @pytest.mark.timeout(240)
-def test_decode_step_reads_the_cache_in_place(one_chip):
+@pytest.mark.parametrize("lower, spec", [(_decode_step, SPEC),
+                                         (_moe_decode_step, MOE)],
+                         ids=["dense", "expert"])
+def test_decode_step_reads_the_cache_in_place(one_chip, lower, spec):
     """The flash-decode kernel reads the stacked caches where they lie:
-    no per-layer relayout of one layer's cache to ``(B, Hkv, S, hd)``,
-    and no whole-cache copy beyond the one per cache of the non-donated
-    input (ROADMAP S5); nor does its q operand cost a cast of ``wq``."""
-    text = _decode_step(one_chip).compile().as_text()
-    relayout = f"f32[{SLOTS},{SPEC.n_kv_heads},{SPEC.max_len}," \
-               f"{SPEC.head_dim}]"
+    no per-layer relayout of one layer's cache to ``(B, Hkv, S, hd)``;
+    the step writes its new rows into the donated caches, with no
+    whole-cache copy; nor does its q operand cost a cast of ``wq``."""
+    text = lower(one_chip).compile().as_text()
+    relayout = f"f32[{SLOTS},{spec.n_kv_heads},{spec.max_len}," \
+               f"{spec.head_dim}]"
     assert _results(text, relayout) == []
-    whole = f"f32[{SPEC.n_layers},{SLOTS},{SPEC.max_len}," \
-            f"{SPEC.n_kv_heads},{SPEC.head_dim}]"
-    copies = [name for name, op in _results(text, whole) if op == "copy"]
-    assert len(copies) <= 2, copies
-    assert _results(text, whole), "the pattern matched no cache at all"
+    whole = _results(text, _whole(spec))
+    assert whole, "the pattern matched no cache at all"
+    assert [name for name, op in whole if op == "copy"] == []
     # the q projection stays one fusion on the f32 weight: handed queries
     # as (B, H, hd), XLA writes a bf16 copy of every layer's wq first
     entry = text[text.index("\nENTRY "):]
-    wq_bf16 = f"bf16[{SPEC.d_model},{SPEC.n_heads * SPEC.head_dim}]"
+    wq_bf16 = f"bf16[{spec.d_model},{spec.n_heads * spec.head_dim}]"
     assert _results(entry, wq_bf16) == []
+
+
+@pytest.mark.timeout(240)
+@pytest.mark.parametrize("rows", [1, SLOTS])
+def test_splice_writes_the_cache_in_place(one_chip, rows):
+    """Admitting a carrier writes its rows into the donated caches: no
+    whole-cache copy, for one row or every slot."""
+    whole = _results(_splice(one_chip, rows).compile().as_text(),
+                     _whole(SPEC))
+    assert whole, "the pattern matched no cache at all"
+    assert [name for name, op in whole if op == "copy"] == []
 
 
 @pytest.mark.timeout(240)
