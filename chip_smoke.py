@@ -41,12 +41,12 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.kernels import ops  # noqa: E402
-from repro.launch import use_compile_cache  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.serving import LMSpec, build_serving_flow, make_request  # noqa: E402
 from repro.serving import kv  # noqa: E402
 
-#: qwen3-1.7b's attention and FFN widths (configs/archs.py) in the serving
-#: plane's own LM; kv.py has no RoPE or qk-norm
+#: qwen3-1.7b's attention and FFN widths (bench/configs/qwen3-1.7b-serve.json)
+#: in the serving plane's own LM; kv.py has no RoPE or qk-norm
 QWEN3_1_7B = LMSpec(vocab=151936, n_heads=16, n_kv_heads=8, head_dim=128,
                     n_layers=28, ffn_mult=3, max_len=512)
 #: kernel-vs-reference logits: max |kernel - ref| over max |ref|.  Both

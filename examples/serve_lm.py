@@ -10,9 +10,6 @@ request — the KV/slot tables ride across on ``__floe_state__`` and every
 response records which model version produced it — while a §III
 tail-latency SLO strategy elastically scales the decode stage.
 
-The seed's standalone loop is still importable as
-``repro.serving.ServingEngine``; this example drives the dataflow plane.
-
 Run:  PYTHONPATH=src python examples/serve_lm.py
 """
 import time

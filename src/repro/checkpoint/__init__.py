@@ -1,8 +1,5 @@
-from .checkpointer import (AsyncCheckpointer, CheckpointCorruptError,
-                           checkpoint_floe_graph, latest_step,
-                           read_floe_meta, restore, restore_floe_graph,
-                           save)
+from .checkpointer import (CheckpointCorruptError, checkpoint_floe_graph,
+                           read_floe_meta, restore_floe_graph)
 
-__all__ = ["AsyncCheckpointer", "CheckpointCorruptError",
-           "checkpoint_floe_graph", "latest_step", "read_floe_meta",
-           "restore", "restore_floe_graph", "save"]
+__all__ = ["CheckpointCorruptError", "checkpoint_floe_graph",
+           "read_floe_meta", "restore_floe_graph"]

@@ -1,20 +1,12 @@
-"""Checkpointing: pellet state objects + train state, with async snapshots.
+"""Checkpointing of a Floe graph: pellet state objects + pending messages.
 
 The paper (§II.A) makes pellet state an *explicit* object precisely so the
 framework can "offer resilience through transparent checkpointing of the
 state object and resuming from the last saved state and the input messages
 available then" — listed as future work there; implemented here:
-
-* ``save / restore``       — pytree (params / TrainState / SSM caches /
-  arbitrary pellet state) to sharded ``.npz`` + msgpack manifest.  Leaves
-  are fetched shard-by-shard (``jax.device_get``) so a multi-host deployment
-  writes only its addressable shards.
-* ``AsyncCheckpointer``    — snapshot thread: the train loop hands over a
-  (jax.device_get-materialized) state and continues; writes never block the
-  step.  Keeps the newest k checkpoints, atomic rename on completion.
-* ``checkpoint_floe_graph`` — engine-level fault tolerance: every stateful
-  flake's state object plus its pending input messages (at-least-once
-  replay on restore).
+``checkpoint_floe_graph`` writes every stateful flake's state object plus
+its pending input messages (at-least-once replay on restore), and
+``restore_floe_graph`` reads them back.
 """
 from __future__ import annotations
 
@@ -22,151 +14,14 @@ import hashlib
 import json
 import os
 import pickle
-import shutil
-import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
-
-import jax
-import numpy as np
+from typing import Any, Dict, Optional
 
 
 class CheckpointCorruptError(RuntimeError):
     """A floe checkpoint failed verification (truncated file, checksum
     mismatch, or unreadable payload).  Raised instead of unpickling
     garbage so a recovery path can fall back to an older checkpoint."""
-
-
-def _flatten(tree: Any) -> Tuple[List[np.ndarray], List[str], Any]:
-    """Materialize leaves on host.  bf16 (and other ml_dtypes) are widened
-    to f32 for the npz container (numpy's format cannot serialize them);
-    the original dtype string is recorded for exact round-trip."""
-    leaves, treedef = jax.tree.flatten(tree)
-    out, dtypes = [], []
-    for l in leaves:
-        a = np.asarray(jax.device_get(l))
-        dtypes.append(str(a.dtype))
-        if a.dtype.kind == "V" or "bfloat16" in str(a.dtype):
-            a = a.astype(np.float32)   # bf16 -> f32 is exact
-        out.append(a)
-    return out, dtypes, treedef
-
-
-def save(path: str, tree: Any, *, step: Optional[int] = None) -> None:
-    """Atomic pytree checkpoint: <path>/arrays.npz + manifest."""
-    tmp = path + ".tmp"
-    os.makedirs(tmp, exist_ok=True)
-    leaves, dtypes, treedef = _flatten(tree)
-    np.savez(os.path.join(tmp, "arrays.npz"),
-             **{f"leaf_{i}": l for i, l in enumerate(leaves)})
-    with open(os.path.join(tmp, "treedef.pkl"), "wb") as f:
-        pickle.dump(treedef, f)
-    with open(os.path.join(tmp, "manifest.json"), "w") as f:
-        json.dump({"n_leaves": len(leaves), "step": step,
-                   "dtypes": dtypes, "time": time.time()}, f)
-    if os.path.exists(path):
-        shutil.rmtree(path)
-    os.replace(tmp, path)
-
-
-def restore(path: str, *, like: Any = None) -> Any:
-    """Restore a pytree; if ``like`` is given, leaves are cast/placed to
-    match its shardings (jax.device_put against the example tree)."""
-    with open(os.path.join(path, "manifest.json")) as f:
-        manifest = json.load(f)
-    with np.load(os.path.join(path, "arrays.npz")) as z:
-        leaves = [z[f"leaf_{i}"] for i in range(manifest["n_leaves"])]
-    with open(os.path.join(path, "treedef.pkl"), "rb") as f:
-        treedef = pickle.load(f)
-    tree = jax.tree.unflatten(treedef, leaves)
-    if like is not None:
-        tree = jax.tree.map(
-            lambda x, ref: jax.device_put(
-                jnp_cast(x, ref),
-                ref.sharding if hasattr(ref, "sharding") else None),
-            tree, like)
-    else:
-        dts = iter(manifest["dtypes"])
-        tree = jax.tree.map(
-            lambda x: _narrow(x, next(dts)), tree)
-    return tree
-
-
-def _narrow(x: np.ndarray, dtype_str: str):
-    if "bfloat16" in dtype_str and str(x.dtype) != dtype_str:
-        import jax.numpy as jnp
-        return np.asarray(jnp.asarray(x, jnp.bfloat16))
-    return x
-
-
-def jnp_cast(x: np.ndarray, ref):
-    import jax.numpy as jnp
-    return jnp.asarray(np.asarray(x)).astype(ref.dtype)
-
-
-def latest_step(root: str) -> Optional[int]:
-    if not os.path.isdir(root):
-        return None
-    steps = []
-    for name in os.listdir(root):
-        if name.startswith("step_") and not name.endswith(".tmp"):
-            try:
-                steps.append(int(name.split("_")[1]))
-            except ValueError:
-                pass
-    return max(steps) if steps else None
-
-
-class AsyncCheckpointer:
-    """Non-blocking checkpointer with retention."""
-
-    def __init__(self, root: str, *, keep: int = 3):
-        self.root = root
-        self.keep = keep
-        self._thread: Optional[threading.Thread] = None
-        self._error: Optional[Exception] = None
-        os.makedirs(root, exist_ok=True)
-
-    def save_async(self, step: int, tree: Any) -> None:
-        self.wait()  # one snapshot in flight at a time
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)),
-                                 tree)
-
-        def work():
-            try:
-                save(os.path.join(self.root, f"step_{step}"), host_tree,
-                     step=step)
-                self._gc()
-            except Exception as e:  # surfaced on next wait()
-                self._error = e
-
-        self._thread = threading.Thread(target=work, daemon=True)
-        self._thread.start()
-
-    def wait(self) -> None:
-        if self._thread is not None:
-            self._thread.join()
-            self._thread = None
-        if self._error is not None:
-            e, self._error = self._error, None
-            raise e
-
-    def restore_latest(self, like: Any = None) -> Tuple[Optional[int], Any]:
-        step = latest_step(self.root)
-        if step is None:
-            return None, None
-        return step, restore(os.path.join(self.root, f"step_{step}"),
-                             like=like)
-
-    def _gc(self) -> None:
-        steps = sorted(s for s in (latest_step(self.root),) if s is not None)
-        all_steps = []
-        for name in os.listdir(self.root):
-            if name.startswith("step_") and not name.endswith(".tmp"):
-                all_steps.append(int(name.split("_")[1]))
-        for s in sorted(all_steps)[:-self.keep]:
-            shutil.rmtree(os.path.join(self.root, f"step_{s}"),
-                          ignore_errors=True)
 
 
 # ---------------------------------------------------------------------------

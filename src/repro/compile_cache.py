@@ -1,15 +1,11 @@
-"""Launchers: mesh construction, multi-pod dry-run, train and serve CLIs.
-
-NOTE: importing `dryrun` sets XLA_FLAGS for 512 host devices — never import
-it from tests or benches; use `mesh`, `steps`, `sharding` directly.
-"""
+"""Where JAX's persistent compilation cache lives for this checkout."""
 from __future__ import annotations
 
 import os
 import pathlib
 
-#: root of the checkout this package runs from (``src/repro/launch/..``)
-CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+#: root of the checkout this package runs from (``src/repro/..``)
+CHECKOUT = pathlib.Path(__file__).resolve().parents[2]
 
 
 def use_compile_cache() -> str:

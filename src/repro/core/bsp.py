@@ -12,9 +12,7 @@ decided at runtime — workers vote to halt, Pregel-style.
 
 The same pattern at the SPMD layer is a ``shard_map`` step with an
 ``all_to_all``/``all_gather`` at the superstep boundary (see
-``examples/stream_clustering.py`` for the distributed-LSH instantiation, and
-the synchronous data-parallel gradient all-reduce in ``launch/train.py``
-which is the degenerate one-superstep case).
+``examples/stream_clustering.py`` for the distributed-LSH instantiation).
 
 ``add_bsp``/``start_bsp`` are the legacy graph-level helpers; new code
 should use the Session API combinator ``Flow.bsp(...)`` plus

@@ -9,8 +9,7 @@ multiple sink edges:
   the edge, so all messages with the same key reach the same sink pellet —
   the streaming MapReduce shuffle (Fig. 1, P9).  This is the pattern the
   paper singles out as missing from generic dataflow frameworks; at the
-  SPMD layer it becomes the MoE ``all_to_all`` dispatch (see
-  ``repro.kernels.moe_dispatch``).
+  SPMD layer it becomes the MoE ``all_to_all`` dispatch.
 * ``BalancedSplit``   — the paper's "more sophisticated strategy ... e.g.
   depending on the numbers of messages pending in the input queue": route to
   the sink with the shortest pending queue (join-the-shortest-queue).

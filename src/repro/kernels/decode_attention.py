@@ -23,9 +23,8 @@ kv heads are masked out of each head's softmax.  The queries arrive as
 one ``(B, H * hd)`` block, the layout the q projection writes: handed
 ``(B, H, hd)``, XLA moves that projection onto bf16 copies of its weight.
 
-This single-token kernel is the unit the serving engine calls per decode
-step; the sequence-sharded (model-axis) distribution around it performs the
-cross-chip partial-softmax combine (see launch/sharding.cache_pspecs).
+This single-token kernel is what the serving plane's decode step calls
+for each layer.
 """
 from __future__ import annotations
 

@@ -13,12 +13,9 @@ Blocked online-softmax attention with explicit VMEM tiling:
   masked to -inf — the block-skip optimization lives in the index-map-level
   choice of ``block_k`` relative to the window width;
 * MXU alignment: block_q/block_k default to 128; head_dim is zero-padded to
-  a multiple of 128 by the ops.py wrapper when needed (smollm hd=64,
-  danube hd=120, zamba2 hd=80).
+  a multiple of 128 by the ops.py wrapper when needed.
 
-Backward is delegated to JAX autodiff over the ref path in training (the
-kernel is the serving/prefill hot path); a custom bwd kernel is a known
-further optimization, recorded in EXPERIMENTS.md.
+Forward only: the kernel is the serving plane's prefill attention.
 """
 from __future__ import annotations
 

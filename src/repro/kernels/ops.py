@@ -1,8 +1,8 @@
-"""jit'd wrappers around the Pallas kernels (padding, routing, interpret).
+"""jit'd wrappers around the Pallas kernels (padding, interpret).
 
 These are the public entry points: they handle TPU lane-alignment padding
-(head dims to multiples of 128), compute MoE routing tables, and expose an
-``interpret=`` switch so the same code paths run on CPU for validation.
+(head dims to multiples of 128) and expose an ``interpret=`` switch so the
+same code paths run on CPU for validation.
 """
 from __future__ import annotations
 
@@ -17,7 +17,6 @@ from . import cluster_distance as _cd
 from . import decode_attention as _dec
 from . import flash_attention as _fa
 from . import moe_decode as _moe_dec
-from . import moe_dispatch as _moe
 from . import ssm_scan as _ssm
 
 LANE = 128
@@ -193,56 +192,3 @@ def cluster_distance_op(x, centroids, *, block_b: int = 128,
         xp = jnp.pad(xp, ((0, pad_b), (0, 0)))
     out = _cd.cluster_distances(xp, cp, block_b=bb, interpret=interpret)
     return out[:B, :K]
-
-
-# ---------------------------------------------------------------------------
-# MoE routing (dense jnp math) + kernel-backed dispatch/combine
-# ---------------------------------------------------------------------------
-
-def route(router_logits: jnp.ndarray, top_k: int, capacity: int):
-    """Compute the dynamic port mapping tables from router logits (T,E).
-
-    Returns (weight (T,k) f32, expert (T,k) i32, pos (T,k) i32,
-    keep (T,k) bool, src_idx (E,C) i32, valid (E,C) bool)."""
-    T, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    weight, expert = jax.lax.top_k(probs, top_k)
-    weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
-    flat_e = expert.reshape(-1)
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)
-    pos_flat = (jnp.cumsum(onehot, axis=0) - 1)
-    pos_flat = jnp.take_along_axis(pos_flat, flat_e[:, None], axis=1)[:, 0]
-    keep_flat = pos_flat < capacity
-    tok = jnp.arange(T * top_k, dtype=jnp.int32) // top_k
-    # out-of-capacity writes fall outside (E,C) and are dropped
-    src_idx = jnp.zeros((E, capacity), jnp.int32).at[
-        flat_e, pos_flat].set(tok, mode="drop")
-    valid = jnp.zeros((E, capacity), bool).at[
-        flat_e, pos_flat].set(True, mode="drop")
-    return (weight, expert, pos_flat.reshape(T, top_k),
-            keep_flat.reshape(T, top_k), src_idx, valid)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def moe_dispatch_op(x, src_idx, valid, *, interpret: bool = False):
-    return _moe.moe_dispatch(x, src_idx, valid, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def moe_combine_op(buf, expert, pos, weight, keep, *,
-                   interpret: bool = False):
-    return _moe.moe_combine(buf, expert, pos, weight, keep,
-                            interpret=interpret)
-
-
-def moe_ffn_pallas(x, router_w, w_gate, w_up, w_down, top_k: int,
-                   capacity: int, *, interpret: bool = False):
-    """End-to-end kernel-backed MoE FFN (route→dispatch→experts→combine)."""
-    weight, expert, pos, keep, src_idx, valid = route(
-        x @ router_w, top_k, capacity)
-    buf = moe_dispatch_op(x, src_idx, valid, interpret=interpret)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, w_gate))
-    h = h * jnp.einsum("ecd,edf->ecf", buf, w_up)
-    out_buf = jnp.einsum("ecf,efd->ecd", h, w_down)
-    return moe_combine_op(out_buf, expert, pos, weight, keep,
-                          interpret=interpret)

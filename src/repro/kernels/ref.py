@@ -2,8 +2,9 @@
 
 These define the semantics; the kernels must ``allclose`` against them for
 every shape/dtype in the test sweeps (kernels run with ``interpret=True`` on
-CPU).  They intentionally share code with the model reference paths so the
-kernels are validated against exactly what the models compute.
+CPU).  The serving LM's ref twins (``serving/kv.py`` ``prefill_ref``,
+``decode_step_ref``) compute through them, so the kernels are validated
+against exactly what the served model computes.
 """
 from __future__ import annotations
 
@@ -116,29 +117,3 @@ def moe_ffn(x: jnp.ndarray, expert_ids: jnp.ndarray, weights: jnp.ndarray,
     u = jnp.einsum("td,edf->tef", xf, w_up.astype(jnp.float32))
     a = jax.nn.silu(g) * u * comb[..., None]
     return jnp.einsum("tef,efd->td", a, w_down.astype(jnp.float32))
-
-
-# ---------------------------------------------------------------------------
-# MoE dispatch / combine (dynamic port mapping)
-# ---------------------------------------------------------------------------
-
-def moe_gather_dispatch(x: jnp.ndarray, src_idx: jnp.ndarray,
-                        valid: jnp.ndarray) -> jnp.ndarray:
-    """Gather token rows into expert buffers.
-
-    x (T,D); src_idx (E,C) int32 source row per expert slot; valid (E,C)
-    bool -> buffers (E,C,D) with invalid slots zeroed."""
-    buf = x[src_idx]                         # (E,C,D)
-    return jnp.where(valid[..., None], buf, 0).astype(x.dtype)
-
-
-def moe_gather_combine(buf: jnp.ndarray, expert: jnp.ndarray,
-                       pos: jnp.ndarray, weight: jnp.ndarray,
-                       keep: jnp.ndarray) -> jnp.ndarray:
-    """Weighted combine of expert outputs back to token rows.
-
-    buf (E,C,D); expert/pos/keep (T,k); weight (T,k) -> y (T,D)."""
-    rows = buf[expert, pos]                  # (T,k,D)
-    rows = jnp.where(keep[..., None], rows, 0)
-    return jnp.sum(rows * weight[..., None].astype(rows.dtype), axis=1
-                   ).astype(buf.dtype)

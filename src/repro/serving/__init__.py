@@ -1,21 +1,18 @@
-"""Serving: LM inference, both the legacy engine and the dataflow plane.
+"""Serving: LM inference as a Floe dataflow (the serving plane).
 
-* ``ServingEngine`` — the seed's standalone continuous-batching loop
-  (kept importable; see ``serving/engine.py``).
-* The serving *plane* — inference expressed as a Floe dataflow on the
-  Session API (``build_serving_flow``): admission/scheduling, a
-  flash-attention prefill stage, a continuously-batched flash-decode
-  stage with checkpointable KV/slot state, live weight hot-swap, elastic
-  decode scaling, and exactly-once response delivery.
+``build_serving_flow`` composes inference on the Session API:
+admission/scheduling, a flash-attention prefill stage, a
+continuously-batched flash-decode stage with checkpointable KV/slot
+state, live weight hot-swap, elastic decode scaling, and exactly-once
+response delivery.  The LM itself (``LMSpec``, prefill, decode step,
+slot splice) lives in ``serving/kv.py``.
 """
 from .dataflow import (TICK, DecodePellet, LMSpec, PrefillPellet,
                        build_serving_flow, init_params, make_request,
                        swapped_flow)
-from .engine import Request, Response, ServingEngine
 from .scheduler import Scheduler
 
 __all__ = [
-    "Request", "Response", "ServingEngine",
     "LMSpec", "init_params", "make_request", "Scheduler",
     "PrefillPellet", "DecodePellet", "build_serving_flow", "swapped_flow",
     "TICK",

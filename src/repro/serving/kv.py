@@ -2,10 +2,9 @@
 
 The serving dataflow (``serving/dataflow.py``) needs a model whose prefill
 is *driven by* the seed ``flash_attention`` Pallas kernel and whose decode
-is driven by ``decode_attention`` — not the dense reference stack in
-``models/`` (which re-implements attention inline).  This module is that
-model: a compact pre-norm transformer whose only attention entry points
-are ``kernels.ops.flash_attention_op`` / ``decode_attention_op``, plus
+is driven by ``decode_attention``.  This module is that model, the one LM
+of the package: a compact pre-norm transformer whose only attention entry
+points are ``kernels.ops.flash_attention_op`` / ``decode_attention_op``, plus
 *ref twins* (same math routed through ``kernels/ref.py``) so kernel-vs-ref
 parity can be asserted **through the dataflow** on stage outputs.
 

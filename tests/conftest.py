@@ -11,9 +11,10 @@ cache.
   compatible with pytest-timeout, which takes over transparently when
   installed (we then skip the built-in watchdog).
 * The JAX persistent compilation cache is enabled
-  (``repro.launch.use_compile_cache``: ``JAX_COMPILATION_CACHE_DIR`` or the
-  checkout's ``.jax_cache/``): the model/kernel smoke tests are dominated
-  by XLA compilation, so warm reruns and cached CI runs cut minutes.
+  (``repro.compile_cache.use_compile_cache``: ``JAX_COMPILATION_CACHE_DIR``
+  or the checkout's ``.jax_cache/``): the kernel and serving tests are
+  dominated by XLA compilation, so warm reruns and cached CI runs cut
+  minutes.
 
 Helpers the tests import live in ``testkit.py``, not here: the benchmark's
 own tests (``bench/tests``) are collected in the same session with a
@@ -32,7 +33,7 @@ import pytest
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
-from repro.launch import use_compile_cache  # noqa: E402
+from repro.compile_cache import use_compile_cache  # noqa: E402
 
 use_compile_cache()
 

@@ -1,80 +1,12 @@
-"""Checkpoint/restart fault tolerance: train state, async snapshots, Floe
-graph state + pending-message replay, elastic resume."""
+"""Checkpoint/restart fault tolerance: Floe graph state + pending-message
+replay, consistent cuts, and the checkpoint file's integrity checks."""
 import os
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.checkpoint import (AsyncCheckpointer, checkpoint_floe_graph,
-                              restore, restore_floe_graph, save)
-from repro.configs import registry
-from repro.data import TokenPipeline
-from repro.launch.steps import make_train_step
-from repro.models import Model
-from repro.optim import init_state
+from repro.checkpoint import checkpoint_floe_graph, restore_floe_graph
 from testkit import wait_until
-
-
-@pytest.fixture(scope="module")
-def setup():
-    cfg = registry.get("smollm-360m").scaled_down()
-    step, model = make_train_step(cfg)
-    jstep = jax.jit(step)
-    pipe = TokenPipeline(cfg, global_batch=4, seq_len=16, seed=3)
-    return cfg, model, jstep, pipe
-
-
-def tree_equal(a, b):
-    fa, fb = jax.tree.leaves(a), jax.tree.leaves(b)
-    return all(np.array_equal(np.asarray(x), np.asarray(y))
-               for x, y in zip(fa, fb))
-
-
-def test_save_restore_roundtrip(tmp_path, setup):
-    cfg, model, jstep, pipe = setup
-    state = init_state(model.init(jax.random.PRNGKey(0)))
-    state, _ = jstep(state, pipe.batch_at(0))
-    path = str(tmp_path / "ckpt")
-    save(path, state, step=1)
-    back = restore(path, like=state)
-    assert tree_equal(state, back)
-
-
-def test_restart_resumes_identical_training(tmp_path, setup):
-    """Kill-and-restart equivalence: train 4 steps straight vs train 2,
-    checkpoint, 'crash', restore, train 2 more — identical final state
-    (deterministic data pipeline + saved optimizer state)."""
-    cfg, model, jstep, pipe = setup
-    s = init_state(model.init(jax.random.PRNGKey(0)))
-    for i in range(4):
-        s, _ = jstep(s, pipe.batch_at(i))
-    straight = s
-
-    s2 = init_state(model.init(jax.random.PRNGKey(0)))
-    for i in range(2):
-        s2, _ = jstep(s2, pipe.batch_at(i))
-    save(str(tmp_path / "c2"), s2, step=2)
-    del s2                                            # "crash"
-    s3 = restore(str(tmp_path / "c2"), like=straight)
-    for i in range(2, 4):
-        s3, _ = jstep(s3, pipe.batch_at(i))
-    assert tree_equal(straight, s3)
-
-
-def test_async_checkpointer_retention(tmp_path, setup):
-    cfg, model, jstep, pipe = setup
-    state = init_state(model.init(jax.random.PRNGKey(0)))
-    ck = AsyncCheckpointer(str(tmp_path / "root"), keep=2)
-    for i in (1, 2, 3):
-        ck.save_async(i, state)
-    ck.wait()
-    names = sorted(os.listdir(str(tmp_path / "root")))
-    assert names == ["step_2", "step_3"]              # retention
-    step, back = ck.restore_latest(like=state)
-    assert step == 3 and tree_equal(state, back)
 
 
 def test_floe_graph_checkpoint_replays_pending(tmp_path):
@@ -119,21 +51,6 @@ def test_floe_graph_checkpoint_replays_pending(tmp_path):
         assert sorted(out) == [22, 25]                # replayed execution
     finally:
         coord2.stop()
-
-
-def test_elastic_resume_smaller_mesh(tmp_path, setup):
-    """Node-failure handling: restore the same checkpoint into a training
-    run configured for fewer replicas (divisor resize) — state restores and
-    training proceeds (single-device stand-in for the re-mesh)."""
-    cfg, model, jstep, pipe = setup
-    s = init_state(model.init(jax.random.PRNGKey(0)))
-    s, _ = jstep(s, pipe.batch_at(0))
-    save(str(tmp_path / "c"), s, step=1)
-    restored = restore(str(tmp_path / "c"), like=s)
-    # half the replicas -> half the global batch, same step function
-    small_pipe = TokenPipeline(cfg, global_batch=2, seq_len=16, seed=3)
-    s2, metrics = jstep(restored, small_pipe.batch_at(1))
-    assert np.isfinite(float(metrics["loss"]))
 
 
 def test_checkpoint_captures_push_pellet_instance_state(tmp_path):

@@ -7,7 +7,7 @@ with one process, a compile cache that can be placed from outside.
 * Importing the package initialises no JAX backend.
 * A process-backed worker pins its JAX to the CPU before its first JAX
   call, whatever platform its parent's environment names.
-* ``launch.use_compile_cache`` leaves a set ``JAX_COMPILATION_CACHE_DIR``
+* ``compile_cache.use_compile_cache`` leaves a set ``JAX_COMPILATION_CACHE_DIR``
   alone and otherwise points at ``.jax_cache/`` in the checkout.
 * ``chip_smoke.py`` refuses to run off the chip, and its two phases pass
   at a tiny size on the CPU with interpreted kernels.
@@ -24,7 +24,7 @@ from testkit import ROOT, load_chip_smoke
 
 from repro.cluster.workers.handle import WorkerHandle
 from repro.kernels import ops
-from repro.launch import use_compile_cache
+from repro.compile_cache import use_compile_cache
 from repro.serving import LMSpec
 
 
@@ -48,7 +48,7 @@ from jax._src import xla_bridge
 src = pathlib.Path(sys.argv[1])
 for path in sorted(src.joinpath("repro").rglob("*.py")):
     parts = path.relative_to(src).with_suffix("").parts
-    if parts[-1] == "__main__" or parts[-2:] == ("launch", "dryrun"):
+    if parts[-1] == "__main__":
         continue
     name = ".".join(parts[:-1] if parts[-1] == "__init__" else parts)
     importlib.import_module(name)

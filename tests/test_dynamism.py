@@ -81,9 +81,11 @@ def test_async_update_zero_downtime_interleaves():
     """Asynchronous update: old in-flight instances run to completion while
     the new logic processes new messages — outputs may interleave."""
     gate = threading.Event()
+    started = threading.Event()
 
     class SlowV1(PushPellet):
         def compute(self, x):
+            started.set()
             gate.wait(timeout=10)
             return ("v1", x)
 
@@ -92,7 +94,9 @@ def test_async_update_zero_downtime_interleaves():
     coord = Coordinator(g).start()
     try:
         coord.inject("p", 0)
-        # old instance now in flight, blocked on the gate
+        # old instance now in flight, blocked on the gate: a popped message
+        # counts in flight before an instance runs it, so wait for the run
+        assert started.wait(timeout=10)
         assert wait_until(lambda: coord.flakes["p"]._inflight == 1)
         coord.update_pellet("p", V2, mode="async")  # returns immediately
         coord.inject("p", 1)

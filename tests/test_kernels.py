@@ -192,51 +192,6 @@ def test_ssm_scan_with_initial_state():
 
 
 # ---------------------------------------------------------------------------
-# MoE dispatch/combine (dynamic port mapping)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("T,D,E,K,C", [
-    (32, 16, 4, 1, 16),
-    (64, 32, 4, 2, 48),
-    (128, 64, 8, 2, 32),   # tight capacity -> drops exercised
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_moe_dispatch_combine_sweep(T, D, E, K, C, dtype):
-    x = rand(0, (T, D), dtype)
-    logits = rand(1, (T, E), jnp.float32)
-    w, e, pos, keep, src, valid = ops.route(logits, K, C)
-    buf = ops.moe_dispatch_op(x, src, valid, interpret=True)
-    bref = ref.moe_gather_dispatch(x, src, valid)
-    assert maxerr(buf, bref) == 0.0          # pure data movement: exact
-    y = ops.moe_combine_op(buf, e, pos, w, keep, interpret=True)
-    yref = ref.moe_gather_combine(bref, e, pos, w, keep)
-    assert maxerr(y, yref) < TOL[dtype]
-
-
-def test_moe_ffn_pallas_matches_model_moe():
-    """Kernel-backed MoE FFN == the model's jnp moe_ffn (same routing)."""
-    from repro.configs.base import ModelConfig, MoEConfig
-    from repro.models.mlp import capacity, moe_ffn
-    T, D, E, K, F = 64, 32, 4, 2, 48
-    cfg = ModelConfig(name="t", family="moe", n_layers=1, d_model=D,
-                      n_heads=2, n_kv_heads=2, d_ff=F, vocab_size=64,
-                      moe=MoEConfig(n_experts=E, top_k=K, d_expert=F))
-    params = {
-        "router": rand(0, (D, E), jnp.float32),
-        "w_gate": rand(1, (E, D, F), jnp.float32),
-        "w_up": rand(2, (E, D, F), jnp.float32),
-        "w_down": rand(3, (E, F, D), jnp.float32),
-    }
-    x = rand(4, (T, D), jnp.float32)
-    want, _ = moe_ffn(params, x, cfg)
-    cap = capacity(T, cfg.moe)
-    got = ops.moe_ffn_pallas(x, params["router"], params["w_gate"],
-                             params["w_up"], params["w_down"], K, cap,
-                             interpret=True)
-    assert maxerr(got, want) < 2e-4
-
-
-# ---------------------------------------------------------------------------
 # cluster distance (array fast-path distance stage)
 # ---------------------------------------------------------------------------
 

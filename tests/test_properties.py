@@ -14,9 +14,6 @@ from repro.adaptation.strategies import (DynamicAdaptation, Observation,
                                          PelletHints, static_allocation)
 from repro.core import Message
 from repro.core.patterns import HashSplit, stable_hash
-from repro.kernels import ops
-from repro.optim.grad_compress import (compress_tree_fused, dequantize_int8,
-                                       zeros_error_like)
 
 SETTINGS = dict(max_examples=25, deadline=None)
 
@@ -47,36 +44,6 @@ def test_stable_hash_spreads(n_keys):
     edges = [stable_hash(("key", i)) % 8 for i in range(n_keys * 8)]
     counts = np.bincount(edges, minlength=8)
     assert counts.max() <= 3.5 * counts.mean()  # no catastrophic skew
-
-
-# ---------------------------------------------------------------------------
-# MoE routing invariants (the shuffle's correctness conditions)
-# ---------------------------------------------------------------------------
-
-@settings(**SETTINGS)
-@given(st.integers(min_value=1, max_value=6),
-       st.integers(min_value=1, max_value=4),
-       st.integers(min_value=8, max_value=64),
-       st.integers(min_value=0, max_value=1000))
-def test_route_invariants(e_pow, k, T, seed):
-    E = 2 ** e_pow
-    k = min(k, E)
-    cap = max(4, T * k // E)
-    logits = jax.random.normal(jax.random.PRNGKey(seed), (T, E))
-    w, e, pos, keep, src, valid = ops.route(logits, k, cap)
-    w, e, pos, keep = map(np.asarray, (w, e, pos, keep))
-    src, valid = np.asarray(src), np.asarray(valid)
-    # weights are a distribution over the chosen experts
-    np.testing.assert_allclose(w.sum(-1), 1.0, atol=1e-5)
-    # kept slots are within capacity and unique per expert
-    assert (pos[keep] < cap).all()
-    for ex in range(E):
-        taken = pos[(e == ex) & keep]
-        assert len(np.unique(taken)) == len(taken)
-    # valid table marks exactly the kept assignments
-    assert valid.sum() == keep.sum()
-    # every valid slot points at a real token row
-    assert (src[valid] >= 0).all() and (src[valid] < T).all()
 
 
 # ---------------------------------------------------------------------------
@@ -140,41 +107,6 @@ def test_simulator_conserves_messages(seed):
 # ---------------------------------------------------------------------------
 # numerics invariants
 # ---------------------------------------------------------------------------
-
-@settings(max_examples=10, deadline=None)
-@given(st.integers(min_value=1, max_value=3),
-       st.integers(min_value=2, max_value=6),
-       st.integers(min_value=0, max_value=100))
-def test_chunked_ce_matches_direct(b, chunks, seed):
-    from repro.launch.steps import chunked_cross_entropy, cross_entropy
-    S, D, V = chunks * 4, 8, 16
-    key = jax.random.PRNGKey(seed)
-    x = jax.random.normal(key, (b, S, D))
-    head = jax.random.normal(jax.random.PRNGKey(seed + 1), (D, V))
-    labels = jax.random.randint(jax.random.PRNGKey(seed + 2), (b, S), 0, V)
-    a = chunked_cross_entropy(x, head, labels, chunk=4)
-    c = cross_entropy(x @ head, labels)
-    np.testing.assert_allclose(float(a), float(c), rtol=2e-5)
-
-
-@settings(max_examples=10, deadline=None)
-@given(st.integers(min_value=0, max_value=100))
-def test_error_feedback_identity(seed):
-    """EF-int8: the telescoping identity sum(dequantized) = sum(grads) -
-    final_error holds exactly — compression is unbiased over time."""
-    key = jax.random.PRNGKey(seed)
-    grads = {"w": jax.random.normal(key, (16, 16))}
-    err = zeros_error_like(grads)
-    total_deq = jnp.zeros((16, 16))
-    total_g = jnp.zeros((16, 16))
-    for i in range(5):
-        g = {"w": jax.random.normal(jax.random.fold_in(key, i), (16, 16))}
-        q, s, err = compress_tree_fused(g, err)
-        total_deq += dequantize_int8(q["w"], s["w"])
-        total_g += g["w"]
-    np.testing.assert_allclose(np.asarray(total_deq + err["w"]),
-                               np.asarray(total_g), atol=1e-4)
-
 
 @settings(max_examples=8, deadline=None)
 @given(st.integers(min_value=1, max_value=30),

@@ -5,9 +5,10 @@ Covers PR 8's tentpole and satellites:
 * multi-column ``ArrayBatch`` (dict-of-arrays) semantics
 * ``__floe_state__`` carry-over across in-place task updates
 * ``Flow.sink(..., exactly_once=True)`` dedup end-to-end
-* the serving dataflow itself — continuous-batching census, kernel-vs-ref
-  numerics *through the dataflow*, checkpoint→kill→restore of in-flight
-  generations, and zero-loss live weight hot-swap with version tags.
+* the serving dataflow itself — continuous-batching census, counters and
+  profiler spans, and zero-loss live weight hot-swap with version tags
+  (every spec's answers, slot-count invariance and checkpoint→kill→restore
+  of in-flight generations are ``test_lm_flow.py``'s).
 """
 import contextlib
 import pickle
@@ -17,7 +18,7 @@ import numpy as np
 import pytest
 from testkit import wait_until
 
-from repro import Flow, FnPellet, PushPellet, Session
+from repro import Flow, FnPellet, PushPellet
 from repro.core.arraybatch import ArrayBatch
 from repro.serving import (LMSpec, Scheduler, build_serving_flow, kv,
                            make_request, swapped_flow)
@@ -373,57 +374,6 @@ class TestServingPlane:
         # both slots ride the same step batch: ~3 shared steps, never the
         # 6 a sequential tier would need (small slack for admission skew)
         assert steps <= 4
-
-    def test_kernel_vs_ref_parity_through_dataflow(self):
-        """The Pallas-kernel plane and the kernels/ref.py twin must emit
-        token-identical responses — parity asserted on stage *outputs*
-        after riding the scheduler/prefill/decode dataflow end-to-end."""
-        reqs = [make_request(i, [1 + i % 5, 7, 3, 2][: 2 + i % 3],
-                             max_new=5, t_sub=float(i)) for i in range(5)]
-        outs = {}
-        for ref_path in (False, True):
-            flow = build_serving_flow(spec=SPEC, n_slots=2,
-                                      default_budget=5, seed=3,
-                                      ref_path=ref_path)
-            with flow.session() as s:
-                s.inject_many("sched", [dict(r) for r in reqs])
-                outs[ref_path] = _responses(s.results(timeout=90))
-        kernel, ref = outs[False], outs[True]
-        assert [r["rid"] for r in kernel] == [r["rid"] for r in ref] \
-            == [0, 1, 2, 3, 4]
-        for rk, rr in zip(kernel, ref):
-            assert rk["tokens"] == rr["tokens"], \
-                f"rid {rk['rid']}: kernel {rk['tokens']} != ref {rr['tokens']}"
-
-    def test_checkpoint_kill_restore_inflight(self, tmp_path):
-        """A consistent cut taken mid-generation restores the KV/slot
-        state and finishes every request after a kill."""
-        flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=8,
-                                  seed=0)
-        path = str(tmp_path / "serving.ckpt")
-        s = flow.session().open()
-        try:
-            s.inject_many("sched",
-                          [make_request(i, [2 + i, 5], max_new=8)
-                           for i in range(3)])
-            decode = s.coordinator.flakes["decode"]._proto
-            assert wait_until(lambda: decode.live.any(), timeout=60)
-            s.checkpoint(path)
-        finally:
-            pre_kill = _responses([m.payload for m in s.coordinator.outputs])
-            s.close()   # kill mid-generation
-        restored = Session.restore(path, flow)
-        with restored:
-            post = _responses(restored.results(timeout=90))
-        by_rid = {}
-        for r in list(pre_kill) + list(post):
-            by_rid.setdefault(r["rid"], []).append(r)
-        assert sorted(by_rid) == [0, 1, 2], f"lost requests: {sorted(by_rid)}"
-        for rid, rs in by_rid.items():
-            for r in rs:
-                assert r["n_new"] == 8, (rid, r)
-            # deterministic weights: a cross-kill duplicate must agree
-            assert len({tuple(r["tokens"]) for r in rs}) == 1
 
     def test_hot_swap_zero_loss_version_tags(self, monkeypatch):
         """Live weight hot-swap mid-stream: every request answered exactly

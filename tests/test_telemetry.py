@@ -6,8 +6,8 @@ live multi-host flow, Prometheus text that parses cleanly, trace contexts
 surviving ArrayBatch stacking / live migration / checkpoint-restore, and a
 totally ordered event bus under concurrent transactions.  Plus the
 satellites: ``inject_many(stacked=True)``, the migration EWMA/histogram
-reset regression, and a loose in-process overhead guard (the strict 5%
-number lives in ``benchmarks/bench_engine.py``).
+reset regression, and a loose in-process overhead guard (no benchmark
+cell measures telemetry's cost yet).
 """
 import json
 import threading
@@ -631,6 +631,6 @@ def test_telemetry_overhead_is_bounded():
     run(True), run(False)                 # warm both paths
     on = min(run(True) for _ in range(3))
     off = min(run(False) for _ in range(3))
-    # generous in-process bound to stay CI-stable; the 5% acceptance
-    # number is measured by benchmarks/bench_engine.py --telemetry
+    # generous in-process bound to stay CI-stable; no benchmark cell
+    # measures telemetry's cost yet
     assert on < off * 1.5 + 0.05, (on, off)
