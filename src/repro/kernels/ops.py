@@ -53,22 +53,24 @@ def _pad_last(x: jnp.ndarray, mult: int = LANE) -> Tuple[jnp.ndarray, int]:
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention_op(q, k, v, *, causal: bool = True,
-                       window: Optional[int] = None, block_q: int = 128,
-                       block_k: int = 128,
+                       window: Optional[int] = None,
+                       block_q: Optional[int] = None,
+                       block_k: Optional[int] = None,
                        interpret: bool = False) -> jnp.ndarray:
-    """Padded/aligned flash attention: q (B,S,H,hd), kv (B,S,Hkv,hd)."""
+    """Padded/aligned flash attention: q (B,S,H,hd), kv (B,S,Hkv,hd).
+    Block sizes not given follow ``flash_attention.block_sizes``."""
     B, Sq, H, hd = q.shape
     scale = 1.0 / (hd ** 0.5)
     qp, _ = _pad_last(q)
     kp, _ = _pad_last(k)
     vp, _ = _pad_last(v)
-    bq = min(block_q, max(8, Sq))
+    bq, bk = _fa.block_sizes(Sq, kp.shape[1], block_q, block_k)
     pad_q = (-Sq) % bq
     if pad_q:
         qp = jnp.pad(qp, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
     out = _fa.flash_attention(qp, kp, vp, causal=causal, window=window,
-                              block_q=bq, block_k=min(block_k, kp.shape[1]),
-                              sm_scale=scale, interpret=interpret)
+                              block_q=bq, block_k=bk, sm_scale=scale,
+                              interpret=interpret)
     return out[:, :Sq, :, :hd]
 
 

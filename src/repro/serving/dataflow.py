@@ -100,7 +100,9 @@ class PrefillPellet(_StagePellet):
 
     in_ports = ("in",)
     out_ports = ("out",)
-    _tokens = _positions = None      # counters, once bound
+    #: counters, once bound (the attention block pair on the kernel path
+    #: only)
+    _tokens = _positions = _attn_visited = _attn_blocks = None
 
     def __init__(self, params: Dict[str, Any], spec: LMSpec, *,
                  version: int = 0, ref_path: bool = False):
@@ -120,6 +122,17 @@ class PrefillPellet(_StagePellet):
             registry, "floe_prefill_positions_total",
             "Prompt positions prefill computed (batch x padded prompt "
             "length), by stage.", stage)
+        if not self.ref_path:
+            self._attn_visited = _counter(
+                registry, "floe_prefill_attn_blocks_visited_total",
+                "(q, kv) block pairs the prefill flash-attention kernel "
+                "visited, summed over layers, batch rows and heads, by "
+                "stage.", stage)
+            self._attn_blocks = _counter(
+                registry, "floe_prefill_attn_blocks_total",
+                "(q, kv) block pairs of the prefill flash-attention "
+                "kernel's full grid, summed over layers, batch rows and "
+                "heads, by stage.", stage)
 
     def compute_array(self, cols: Any) -> Any:
         if not isinstance(cols, dict) or "tokens" not in cols:
@@ -142,6 +155,12 @@ class PrefillPellet(_StagePellet):
         if self._tokens is not None:
             self._tokens.inc(int(host_lengths.sum()))
             self._positions.inc(host_tokens.size)
+        if self._attn_visited is not None:
+            visited, full = kv.prefill_attn_blocks(self.spec,
+                                                   host_tokens.shape[1])
+            rows = B * self.spec.n_heads
+            self._attn_visited.inc(rows * visited)
+            self._attn_blocks.inc(rows * full)
         return {
             "rid": _np32(cols["rid"]), "slot": _np32(cols["slot"]),
             "length": _np32(cols["length"]), "budget": _np32(cols["budget"]),

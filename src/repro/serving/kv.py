@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import flash_attention as kfa
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.moe_decode import fetch_list
@@ -199,6 +200,19 @@ def prefill(params, tokens, lengths, *, spec: LMSpec, interpret: bool):
             q, k, v, causal=True, interpret=interpret, **window),
         lambda h, ids, w, wg, wu, wd, l: kops.moe_grouped_op(
             h, ids, w, wg, wu, wd, l, interpret=interpret))
+
+
+def prefill_attn_blocks(spec: LMSpec, seq: int) -> Tuple[int, int]:
+    """(q, kv) block pairs ``prefill``'s flash attention visits over
+    ``seq`` prompt positions, and those of its full grid: summed over
+    layers, for one batch row and head."""
+    visited = full = 0
+    for l in range(spec.n_layers):
+        w = spec.window(l)
+        v, f = kfa.band_blocks(seq, seq, *kfa.block_sizes(seq, seq),
+                               True, w)
+        visited, full = visited + v, full + f
+    return visited, full
 
 
 def prefill_ref(params, tokens, lengths, *, spec: LMSpec):

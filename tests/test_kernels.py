@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import decode_attention as dec
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -62,6 +63,98 @@ def test_flash_attention_non_causal():
                                  block_k=32, interpret=True)
     want = ref.attention(q, k, v, causal=False)
     assert maxerr(got, want) < TOL[jnp.float32]
+
+
+def _open_blocks(Sq, Skv, bq, bk, causal, window):
+    """Brute force over the kernel's padded grid: for each (q block, kv
+    block) pair, how many of its (query, key) pairs the masks leave
+    open, as an (nq, nk) array."""
+    nq, nk = -(-Sq // bq), -(-Skv // bk)
+    q = np.arange(nq * bq)[:, None]
+    k = np.arange(nk * bk)[None, :]
+    open_ = (k < Skv) & (q >= 0)
+    if causal:
+        open_ &= k <= q
+    if window is not None:
+        open_ &= k > q - window
+    return open_.reshape(nq, bq, nk, bk).sum(axis=(1, 3))
+
+
+def _open_pairs(Sq, Skv, bq, bk, causal, window):
+    return int((_open_blocks(Sq, Skv, bq, bk, causal, window) > 0).sum())
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_band_matches_brute_force(causal):
+    """Over many shapes: the band is the pairs holding an open (query,
+    key) pair, query block by query block in kv order; its first and last
+    flags mark each query block's ends, and its edge flag each pair with a
+    masked (query, key)."""
+    for S, bq, bk in [(64, 8, 8), (70, 16, 8), (96, 8, 32), (40, 16, 16),
+                      (45, 5, 3)]:
+        for window in (None, 1, 5, 8, 9, 16, 23, 33, 100):
+            opened = _open_blocks(S, S, bq, bk, causal, window)
+            pairs = fa.band(S, S, bq, bk, causal, window)
+            qi, ki, flags = pairs.T
+            assert [(a, b) for a, b in zip(qi, ki)] == \
+                sorted(zip(*np.nonzero(opened))), (S, bq, bk, window)
+            first = np.r_[True, qi[1:] != qi[:-1]]
+            last = np.r_[qi[1:] != qi[:-1], True]
+            assert ((flags & fa.FIRST) != 0).tolist() == first.tolist()
+            assert ((flags & fa.LAST) != 0).tolist() == last.tolist()
+            assert ((flags & fa.EDGE) != 0).tolist() == \
+                (opened[qi, ki] < bq * bk).tolist()
+
+
+@pytest.mark.parametrize("B,S,H,Hkv,hd,window,bq,bk", [
+    (1, 128, 4, 2, 64, 40, 32, 32),     # window not a multiple of the block
+    (1, 128, 4, 2, 64, 34, 32, 32),     # a band edge pair with one open key
+    (1, 128, 4, 2, 64, 63, 32, 32),     # a band edge pair with one masked
+    (2, 128, 4, 4, 64, 8, 32, 32),      # window smaller than a block, MHA
+    (1, 128, 8, 1, 64, 200, 32, 32),    # window longer than the sequence
+    (1, 100, 6, 2, 32, 24, 32, 32),     # ragged seq (pad path), GQA 3:1
+    (1, 128, 4, 2, 64, None, 32, 64),   # full causal, wider kv blocks
+    (1, 160, 4, 1, 64, 48, 64, 32),     # wider q blocks, MQA
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_band(B, S, H, Hkv, hd, window, bq, bk, dtype):
+    """The kernel visits only the band, and the band is exactly the block
+    pairs holding an open (query, key) pair: the answer is the
+    reference's."""
+    assert fa.band_blocks(S, S, bq, bk, True, window)[0] == \
+        _open_pairs(S, S, bq, bk, True, window)
+    q = rand(0, (B, S, H, hd), dtype)
+    k = rand(1, (B, S, Hkv, hd), dtype)
+    v = rand(2, (B, S, Hkv, hd), dtype)
+    got = ops.flash_attention_op(q, k, v, causal=True, window=window,
+                                 block_q=bq, block_k=bk, interpret=True)
+    want = ref.attention(q, k, v, causal=True, window=window)
+    assert maxerr(got, want) < TOL[dtype]
+
+
+def _grid(B, S, H, Hkv, causal, window, bq, bk):
+    """The grid of the one ``pallas_call`` the kernel traces to."""
+    q = jax.ShapeDtypeStruct((B, S, H, 128), jnp.float32)
+    kv_ = jax.ShapeDtypeStruct((B, S, Hkv, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
+        interpret=True))(q, kv_, kv_)
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("causal,window,visited", [
+    (True, None, 528),          # Mellum2's full layer at 4,096 positions
+    (True, 1024, 252),          # and a windowed one: 8 blocks + diagonal
+    (False, None, 1024),        # not causal: the full grid
+])
+def test_flash_attention_grid_is_the_band(causal, window, visited):
+    """The kernel's grid has ``band_blocks(...)[0]`` steps per batch row
+    and head, not the full grid, except where nothing is masked."""
+    got = fa.band_blocks(4096, 4096, 128, 128, causal, window)
+    assert got == (visited, 32 * 32)
+    assert _grid(1, 4096, 4, 2, causal, window, 128, 128) == (4, visited)
 
 
 # ---------------------------------------------------------------------------

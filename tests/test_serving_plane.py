@@ -361,6 +361,45 @@ class TestServingPlane:
             assert pellet._kv_read.value - before[0] == want
             assert pellet._kv_tiles.value - before[1] == 2 * 2 * 3 * 2
 
+    @pytest.mark.parametrize("width", [16, 64])
+    def test_prefill_attn_block_counters(self, width):
+        """One prefill of the windowed expert spec advances the attention
+        block counters by exactly ``band_blocks``' counts, summed over its
+        windowed and full layers, batch rows and heads; the ref path
+        leaves them alone."""
+        from types import SimpleNamespace
+        from testkit import SPECS
+        from repro.kernels.flash_attention import band_blocks, block_sizes
+        from repro.serving.dataflow import PrefillPellet
+        from repro.telemetry import MetricsRegistry
+        spec = next(sp for sp in SPECS
+                    if sp.n_experts and len(set(sp.windows)) > 1)
+        B = 2
+        want = [0, 0]
+        for l in range(spec.n_layers):
+            w = spec.window(l)
+            counts = band_blocks(width, width,
+                                 *block_sizes(width, width), True, w)
+            want = [a + B * spec.n_heads * c for a, c in zip(want, counts)]
+        cols = {"tokens": np.ones((B, width), np.int32),
+                "length": np.array([width, 3], np.int32),
+                "rid": np.arange(B, dtype=np.int32),
+                "slot": np.arange(B, dtype=np.int32),
+                "budget": np.full(B, 4, np.int32), "t_sub": np.zeros(B)}
+        telemetry = SimpleNamespace(registry=MetricsRegistry())
+        params = kv.init_params(spec, 0)
+        kernel = PrefillPellet(params, spec)
+        kernel.bind_telemetry(telemetry, "prefill")
+        kernel.compute_array(cols)
+        assert [kernel._attn_visited.value,
+                kernel._attn_blocks.value] == want
+        ref = PrefillPellet(params, spec, ref_path=True)
+        ref.bind_telemetry(telemetry, "prefill")
+        ref.compute_array(cols)
+        assert ref._attn_visited is None and ref._attn_blocks is None
+        assert [kernel._attn_visited.value,
+                kernel._attn_blocks.value] == want
+
     def test_paired_requests_share_steps(self):
         flow = build_serving_flow(spec=SPEC, n_slots=2, default_budget=4,
                                   seed=0)

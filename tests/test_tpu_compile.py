@@ -72,6 +72,16 @@ def _flash(chip):
                                         interpret=False)
 
 
+def _flash_window(chip):
+    """One windowed layer of the expert model's prefill, at 4,096
+    positions."""
+    q = _sds(chip, (1, MOE_PROMPT, MOE.n_heads, MOE.head_dim))
+    k = _sds(chip, (1, MOE_PROMPT, MOE.n_kv_heads, MOE.head_dim))
+    return ops.flash_attention_op.lower(q, k, k, causal=True,
+                                        window=MOE.windows[0],
+                                        interpret=False)
+
+
 def _cache(chip, spec=SPEC):
     """The decode pellet's stacked ``(L, n_slots, max_len, Hkv, hd)``
     cache.  The lowerings pass this one shape for both caches: ``.lower()``
@@ -146,7 +156,8 @@ def _splice(chip, rows=3):
 
 #: the Pallas kernel each program calls, by the ``name=`` of its
 #: ``pallas_call``: the device trace names the kernel's operation so
-KERNEL_OF = {_flash: "flash_attention", _decode_attention: "decode_attention",
+KERNEL_OF = {_flash: "flash_attention", _flash_window: "flash_attention",
+             _decode_attention: "decode_attention",
              _cluster_distance: "cluster_distance",
              _prefill: "flash_attention", _decode_step: "decode_attention",
              _moe_decode: "moe_decode", _moe_prefill: "flash_attention",
@@ -157,7 +168,7 @@ KERNEL_OF = {_flash: "flash_attention", _decode_attention: "decode_attention",
 @pytest.mark.parametrize("lower", [_flash, _decode_attention,
                                    _cluster_distance, _prefill,
                                    _decode_step, _moe_decode, _moe_prefill,
-                                   _moe_decode_step],
+                                   _moe_decode_step, _flash_window],
                          ids=lambda f: f.__name__.lstrip("_"))
 def test_compiles_for_one_v5e_chip(one_chip, lower):
     compiled = lower(one_chip).compile()
